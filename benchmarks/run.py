@@ -129,8 +129,9 @@ def check() -> None:
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     # sharded smoke runs on a forced-4-device CPU backend so the cohort-axis
     # collectives are actually in the lowering (XLA_FLAGS is read at jax
-    # init, hence a subprocess env, not a runtime switch)
-    shard_env = dict(env)
+    # init, hence a subprocess env, not a runtime switch); pinned to the
+    # CPU, since these are rehearsals and not chip runs
+    shard_env = dict(env, JAX_PLATFORMS="cpu")
     shard_env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                               " --xla_force_host_platform_device_count=4"
                               ).strip()

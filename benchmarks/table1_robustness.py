@@ -39,7 +39,8 @@ def run(quick: bool = True, out: str = "results/table1.json",
                         malicious_frac=0.2 if attack == "attacked" else 0.0,
                         attack_lambda=20.0, local_steps=2, batch=4,
                         seq_len=32, lr=0.05, participation=0.5,
-                        eval_every=max(rounds // 4, 1), seed=seed, quiet=True)
+                        eval_every=max(rounds // 4, 1), seed=seed,
+                        reduced=True, quiet=True)
                     res[tag] = dict(global_acc=h["final_acc"],
                                     local_acc=h["final_local_acc"],
                                     secs=round(time.time() - t0, 1))

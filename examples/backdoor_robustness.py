@@ -13,7 +13,8 @@ ROUNDS, CLIENTS = 12, 8
 print("=== clean runs ===")
 clean = {s: run_fl("smollm-135m", ROUNDS, CLIENTS, strategy=s,
                    arch_mode="both", local_steps=2, batch=4, seq_len=32,
-                   lr=0.05, eval_every=6, seed=0, quiet=True)["final_acc"]
+                   lr=0.05, eval_every=6, seed=0, reduced=True,
+                   quiet=True)["final_acc"]
          for s in ["fedfa", "nefl"]}
 print(clean)
 
@@ -22,7 +23,7 @@ attacked = {s: run_fl("smollm-135m", ROUNDS, CLIENTS, strategy=s,
                       arch_mode="both", malicious_frac=0.2,
                       attack_lambda=20.0, local_steps=2, batch=4,
                       seq_len=32, lr=0.05, eval_every=6, seed=0,
-                      quiet=True)["final_acc"]
+                      reduced=True, quiet=True)["final_acc"]
             for s in ["fedfa", "nefl"]}
 print(attacked)
 

@@ -3,10 +3,12 @@
 check   Lower + compile the canonical program set (sync round on data-only
         and 2x2 meshes, standalone aggregation, async admit + merge, fused
         quantile) and print every declared contract in one table, plus the
-        cache/donation passes.  Forces 4 host devices via a subprocess
-        re-exec when the host has fewer (XLA reads
-        ``--xla_force_host_platform_device_count`` at jax init, so it
-        cannot be set in-process).  Exit 1 on any FAIL.  ``--json PATH``
+        cache/donation passes.  A CPU rehearsal: the command re-executes
+        itself in a child pinned to the CPU with 4 forced host devices
+        (XLA reads ``--xla_force_host_platform_device_count`` at jax init,
+        so it cannot be set in-process), and the parent never initializes
+        a backend, so it holds no accelerator the child would need.
+        Exit 1 on any FAIL.  ``--json PATH``
         additionally writes the machine-readable report (measured values,
         violations, peak estimates, blame tables) to PATH — the flag
         rides through the re-exec, so the forced-device child writes it.
@@ -28,19 +30,20 @@ _FORCE_FLAG = "--xla_force_host_platform_device_count=4"
 def _reexec_with_devices(argv) -> int:
     env = dict(os.environ)
     env[_CHILD_ENV] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {_FORCE_FLAG}".strip()
     return subprocess.call([sys.executable, "-m", "repro.analysis"] + argv,
                            env=env)
 
 
 def _cmd_check(args) -> int:
+    if not os.environ.get(_CHILD_ENV):
+        return _reexec_with_devices(sys.argv[1:])
     import jax
     if jax.device_count() < 4:
-        if os.environ.get(_CHILD_ENV):
-            print(f"ERROR: forced-device child still sees only "
-                  f"{jax.device_count()} device(s)", file=sys.stderr)
-            return 2
-        return _reexec_with_devices(sys.argv[1:])
+        print(f"ERROR: forced-device child still sees only "
+              f"{jax.device_count()} device(s)", file=sys.stderr)
+        return 2
 
     from repro.analysis import format_table
     from repro.analysis import programs

@@ -8,20 +8,20 @@ leaf offsets/shapes/dtypes, per-row segment ids, depth-stage info and graft
 gather maps) and reimplements the algorithm as a handful of segment-wise
 passes over the flat cohort buffer:
 
-  * graft (Alg. 2)          — one flat gather per client,
+  * graft (Alg. 2)          — per-leaf row gathers per client,
   * trimmed norms (§4.3)    — per-(client, segment) quantile threshold AND
                               trimmed sum-of-squares fused into ONE pass
                               over each cohort row via the Pallas
                               ``fedfa_quantile`` kernel on TPU (jnp top_k
-                              tail path on CPU),
+                              tail path elsewhere),
   * (M', γ) accumulation    — two fused weighted reductions over the client
                               axis via the Pallas ``scaled_accum`` kernel on
-                              TPU (pure-jnp ``ref`` fallback on CPU).
+                              TPU (the jnp ``ref`` path elsewhere).
 
 Per-client weights that vary only per (leaf, row) — depth gates, data
-counts, scaling factors α — live in small (m, n_segments) tables gathered
-onto the buffer through ``row_of``, so the elementwise work is a single
-fused pass regardless of how many leaves the model has.
+counts, scaling factors α — live in small (m, n_segments) tables broadcast
+onto the buffer leaf by leaf (``_expand_segments``), so the elementwise
+work is a single fused pass regardless of how many leaves the model has.
 """
 from __future__ import annotations
 
@@ -41,6 +41,7 @@ from repro.configs.base import ArchConfig
 from repro.core.fedfa import _path_stage_info
 from repro.core.masking import (AX, active_fraction, axis_mask_tree,
                                 mask_density)
+from repro.kernels import use_pallas
 from repro.kernels.fedfa_agg import ops as agg_ops
 from repro.kernels.fedfa_quantile import multilevel as quant_ml
 from repro.kernels.fedfa_quantile import ops as quant_ops
@@ -85,7 +86,6 @@ class FlatIndex:
     def __init__(self, params: Params, pad_to: int = 1):
         leaves, self.treedef = tree_flatten_with_path(params)
         specs, row_of, seg_row, seg_stage0 = [], [], [], []
-        g_base, g_row, g_rest = [], [], []
         off = seg = 0
         for path, x in leaves:
             stacked, stage = _path_stage_info(path)
@@ -99,15 +99,6 @@ class FlatIndex:
                 np.arange(seg, seg + lead, dtype=np.int32), rest))
             seg_row.extend(range(lead))
             seg_stage0.extend([stacked and stage == 0] * lead)
-            rel = np.arange(size, dtype=np.int64)
-            if stacked and stage == 0:       # graft gathers along the rows
-                g_base.append(off + rel % rest)
-                g_row.append((rel // rest).astype(np.int32))
-                g_rest.append(np.full(size, rest, np.int32))
-            else:                            # identity (g_rest = 0)
-                g_base.append(off + rel)
-                g_row.append(np.zeros(size, np.int32))
-                g_rest.append(np.zeros(size, np.int32))
             off += size
             seg += lead
         self.leaves = tuple(specs)
@@ -117,16 +108,9 @@ class FlatIndex:
         self.n_padded = off + pad
         if pad:                      # inert tail: density 0, identity graft
             row_of.append(np.zeros(pad, np.int32))
-            rel = off + np.arange(pad, dtype=np.int64)
-            g_base.append(rel)
-            g_row.append(np.zeros(pad, np.int32))
-            g_rest.append(np.zeros(pad, np.int32))
         self.row_of = np.concatenate(row_of)
         self.seg_row = np.asarray(seg_row, np.int32)
         self.seg_stage0 = np.asarray(seg_stage0)
-        self.g_base = np.concatenate(g_base).astype(np.int32)
-        self.g_row = np.concatenate(g_row)
-        self.g_rest = np.concatenate(g_rest)
 
 
 def _segment_maps(index: FlatIndex):
@@ -240,11 +224,45 @@ def _density_and_fraction(cfg: ArchConfig, index: FlatIndex, mk: WidthMasks):
 
 
 def _graft_flat(index: FlatIndex, buf: jax.Array, gmap: jax.Array) -> jax.Array:
-    """Alg. 2 on the flat buffer: one gather (identity off stage 0)."""
-    src = jnp.asarray(index.g_base) \
-        + jnp.take(gmap, jnp.asarray(index.g_row), mode="clip") \
-        * jnp.asarray(index.g_rest)
-    return jnp.take(buf, src, mode="clip")
+    """Alg. 2 on the flat buffer: stage-0 row r of every stacked leaf takes
+    row gmap[r]; everything else is the identity.  Each leaf's rows move
+    as whole (lead, rest) row gathers, so no N-sized index is built."""
+    parts = []
+    for s in index.leaves:
+        x = buf[s.offset:s.offset + s.size]
+        if s.stacked and s.stage == 0:
+            rows = jnp.take(gmap, jnp.arange(s.lead), mode="clip")
+            x = jnp.take(x.reshape(s.lead, s.rest), rows, axis=0,
+                         mode="clip").reshape(-1)
+        parts.append(x)
+    if index.n_padded > index.n:
+        parts.append(buf[index.n:])
+    return jnp.concatenate(parts)
+
+
+def _expand_segments(index: FlatIndex, w: jax.Array,
+                     fill: float = 0.0) -> jax.Array:
+    """(m, S) per-segment values -> (m, n_padded) columns: each leaf row's
+    value broadcast over its ``rest`` columns, ``fill`` on the inert tail.
+    Broadcasts and one concatenate, which fuse into the consumer — a
+    column gather through ``row_of`` would instead come out of XLA as an
+    (N, m) gather whose minor m axis the TPU pads to 128 lanes."""
+    m = w.shape[0]
+    parts = [jnp.broadcast_to(w[:, s.seg0:s.seg0 + s.lead, None],
+                              (m, s.lead, s.rest)).reshape(m, s.size)
+             for s in index.leaves]
+    if index.n_padded > index.n:
+        parts.append(jnp.full((m, index.n_padded - index.n), fill, w.dtype))
+    return jnp.concatenate(parts, axis=1)
+
+
+def _segment_max(index: FlatIndex, x: jax.Array) -> jax.Array:
+    """(m, n_padded) -> (m, S): per-(row, segment) max, leaf by leaf (the
+    inert tail belongs to no segment)."""
+    m = x.shape[0]
+    return jnp.concatenate(
+        [jnp.max(x[:, s.offset:s.offset + s.size].reshape(m, s.lead, s.rest),
+                 axis=2) for s in index.leaves], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -263,33 +281,17 @@ def update_dtype_of(name: str):
             "int8": jnp.int8}[name]
 
 
-def _quant_maps(index: FlatIndex):
-    """Static column -> scale-slot map for quantized admission, memoized on
-    the index.  ``col_of`` (n_padded,) int32 sends each buffer position to
-    its segment's scale column, with the inert pad tail sent to the extra
-    slot S — that slot always carries scale 0, so the int8 roundtrip cannot
-    inject nonzero bits into the N-pad."""
-    maps = getattr(index, "_quant_maps", None)
-    if maps is None:
-        seg_id, _, _ = _segment_maps(index)
-        col_of = seg_id.astype(np.int32).copy()
-        col_of[col_of < 0] = index.n_segments
-        maps = (col_of,)
-        index._quant_maps = maps
-    return maps
-
-
 def quantize_cohort(index: FlatIndex, x: jax.Array,
                     update_dtype: str) -> Tuple[jax.Array, jax.Array]:
     """Quantize a grafted, density-masked (m, n_padded) f32 cohort to the
     admission dtype.  Returns (x_q, scales (m, S) f32).
 
     int8: symmetric per-(client, segment) scales — scale = max|x|/127 over
-    the segment, computed by one scatter-max into an (m, S+1) table (slot S
-    collects the inert pad tail and is dropped).  All-zero segments keep
-    scale 0, so both quantize and dequantize map them to exact zeros.
-    bf16: a plain downcast; scales are all-ones so the fused consumers
-    treat both tiers uniformly.  f32 passes through (identity scales).
+    the segment, one max-reduction per leaf.  All-zero segments keep scale
+    0, so both quantize and dequantize map them to exact zeros, and the
+    inert pad tail stores zeros.  bf16: a plain downcast; scales are
+    all-ones so the fused consumers treat both tiers uniformly.  f32
+    passes through (identity scales).
     """
     m = x.shape[0]
     S = index.n_segments
@@ -297,32 +299,25 @@ def quantize_cohort(index: FlatIndex, x: jax.Array,
         return x, jnp.ones((m, S), jnp.float32)
     if update_dtype == "bf16":
         return x.astype(jnp.bfloat16), jnp.ones((m, S), jnp.float32)
-    (col_of,) = _quant_maps(index)
-    col = jnp.asarray(col_of)
-    seg_max = jnp.zeros((m, S + 1), jnp.float32).at[:, col].max(jnp.abs(x))
-    scales = seg_max[:, :S] / 127.0
-    safe = jnp.where(seg_max > 0, seg_max / 127.0, 1.0)       # (m, S+1)
-    q = jnp.clip(jnp.round(x / jnp.take(safe, col, axis=1)), -127.0, 127.0)
-    # belt and braces on the inert tail: its scale slot is 0 (so dequant is
-    # zero regardless), but keep the stored bits zero too
-    q = jnp.where(jnp.asarray(col_of == S)[None, :], 0.0, q)
+    seg_max = _segment_max(index, jnp.abs(x))                  # (m, S)
+    scales = seg_max / 127.0
+    safe = jnp.where(seg_max > 0, scales, 1.0)
+    q = jnp.clip(jnp.round(x / _expand_segments(index, safe, fill=1.0)),
+                 -127.0, 127.0)
+    if index.n_padded > index.n:
+        q = q.at[:, index.n:].set(0.0)
     return q.astype(jnp.int8), scales
 
 
 def dequantize_cohort(index: FlatIndex, x_q: jax.Array,
                       scales: jax.Array) -> jax.Array:
     """f32 (m, n_padded) view of a quantized cohort: x_q · scale[col].  The
-    inert pad tail reads the implicit scale-0 slot, so it dequantizes to
-    exact zeros.  bf16 cohorts carry all-ones scales (plain upcast).  Used
+    inert pad tail expands to scale 0, so it dequantizes to exact zeros.  bf16 cohorts carry all-ones scales (plain upcast).  Used
     by error feedback, oracles and jnp fallbacks — the hot aggregation path
     never materializes this (m, N) product; dequantization is fused into
     the kernels via per-segment scale tables."""
-    (col_of,) = _quant_maps(index)
-    m = x_q.shape[0]
-    full = jnp.concatenate(
-        [scales.astype(jnp.float32), jnp.zeros((m, 1), jnp.float32)], axis=1)
-    return x_q.astype(jnp.float32) * jnp.take(full, jnp.asarray(col_of),
-                                              axis=1)
+    return x_q.astype(jnp.float32) \
+        * _expand_segments(index, scales.astype(jnp.float32))
 
 
 def _row_quantile(rows_abs: jax.Array, q: jax.Array, trim: float) -> jax.Array:
@@ -371,7 +366,7 @@ def _rows_trimmed_stats(rows: jax.Array, q: jax.Array, trim: float,
     dtype, read once); the jnp path materializes the f32 product first.
     """
     m, R, L = rows.shape
-    if use_kernel or interpret:
+    if use_pallas(use_kernel, interpret):
         t, sq = quant_ops.row_trimmed_stats(
             rows.reshape(m * R, L), jnp.repeat(q, R),
             scale=None if scale is None else scale.reshape(m * R),
@@ -434,11 +429,10 @@ def _cohort_norms(index: FlatIndex, xm: jax.Array, fracs: jax.Array,
     extra = () if scales is None else (scales,)
     if not csh.shardable(mesh, xm.shape[0]):
         return norms_local(xm, fracs, *extra)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     ms = csh.model_shards(mesh)
     extra_spec = () if scales is None else (P("data", None),)
-    if (ms > 1 and (use_kernel or interpret)
+    if (ms > 1 and use_pallas(use_kernel, interpret)
             and xm.shape[1] % (ms * quant_ml.TILE) == 0):
         seg_id, seg_len, leaf_of = _segment_maps(index)
 
@@ -447,21 +441,20 @@ def _cohort_norms(index: FlatIndex, xm: jax.Array, fracs: jax.Array,
             _, sq = quant_ml.segmented_trimmed_stats(
                 xm_l, seg_l[0], jnp.asarray(seg_len), q_seg,
                 scales=rest[0] if rest else None,
-                axis_name=csh.MODEL_AXIS,
-                interpret=interpret or jax.default_backend() != "tpu")
+                axis_name=csh.MODEL_AXIS, interpret=interpret)
             return jnp.sqrt(sq)
 
         # seg_id enters as a host constant (constvar, not a broadcast eqn)
         # so the traced program's only row-sized read is the kernel itself
-        return shard_map(
+        return jax.shard_map(
             norms_2d, mesh=mesh,
             in_specs=(P("data", "model"), P("data", None),
                       P(None, "model")) + extra_spec,
-            out_specs=P("data", None), check_rep=False)(
+            out_specs=P("data", None), check_vma=False)(
                 xm, fracs, np.asarray(seg_id)[None, :], *extra)
-    return shard_map(norms_local, mesh=mesh,
+    return jax.shard_map(norms_local, mesh=mesh,
                      in_specs=(P("data", None), P("data", None)) + extra_spec,
-                     out_specs=P("data", None), check_rep=False)(
+                     out_specs=P("data", None), check_vma=False)(
                          xm, fracs, *extra)
 
 
@@ -512,11 +505,9 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
     if scales is not None and graft and not pregrafted:
         raise ValueError("quantized cohorts must be grafted before "
                          "quantization (pass pregrafted=True)")
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+    use_kernel = use_pallas(use_kernel, interpret)
     ms = csh.model_shards(mesh)
-    two_d = (ms > 1 and csh.shardable(mesh, x.shape[0])
-             and (use_kernel or interpret)
+    two_d = (ms > 1 and csh.shardable(mesh, x.shape[0]) and use_kernel
              and index.n_padded % (ms * quant_ml.TILE) == 0)
     constrain = ((lambda a: csh.constrain_cohort_buffer(a, mesh)) if two_d
                  else (lambda a: csh.constrain_cohort(a, mesh)))
@@ -527,7 +518,6 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
         # left to propagation, GSPMD reshards the per-leaf concatenate onto
         # the model axis with a zero-pad + row-width all-reduce — exactly
         # the model-replicated (m/D, N) transient this path retires
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def _dens_local(mk):
@@ -536,12 +526,12 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
             k = jax.lax.axis_index(csh.MODEL_AXIS)
             return jax.lax.dynamic_slice_in_dim(d, k * cols, cols, axis=1), f
 
-        dens, fracs = shard_map(
+        dens, fracs = jax.shard_map(
             _dens_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(csh.DATA_AXIS), masks),),
             out_specs=(P(csh.DATA_AXIS, csh.MODEL_AXIS),
                        P(csh.DATA_AXIS, None)),
-            check_rep=False)(masks)
+            check_vma=False)(masks)
     else:
         dens, fracs = dens_fn(masks)
         dens = constrain(dens)
@@ -573,8 +563,7 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
             / jnp.maximum(jnp.sum(valid), 1.0)
         alpha = mean_norms / jnp.maximum(norms, eps)
 
-    row_of = jnp.asarray(index.row_of)
-    gather = lambda w: jnp.take(w, row_of, axis=1, mode="clip")     # (m, N)
+    gather = functools.partial(_expand_segments, index)          # (m, N)
     if alpha is None:
         warow = dwrow
     else:

@@ -398,7 +398,11 @@ def flat_round(g_buf: jax.Array, c_buf, cfg: ArchConfig,
             c_buf = fresh_quant_state(index, m, fl.update_dtype)
     elif c_buf is None or isinstance(c_buf, tuple) \
             or c_buf.is_deleted() or c_buf.shape[0] != m:
-        c_buf = jnp.zeros((m, index.n_padded), jnp.float32)
+        # born in its resident layout: the next round's donated buffer
+        # then matches this one's, and the program is not traced again
+        c_buf = jnp.zeros((m, index.n_padded), jnp.float32, device=None
+                          if mesh is None
+                          else cohort_sh.cohort_buffer_sharding(mesh))
     cms_in = default_class_masks(cms, cfg, fl, m)
     # split per-client keys HOST-side (see make_flat_round), for the REAL
     # rows only: padded cohorts must hand row i the same key the unpadded
@@ -467,7 +471,8 @@ def run_rounds(global_params: Params, cfg: ArchConfig, fl: FLConfig,
                rounds: int, data_fn: Callable[[int], Tuple[Sequence[ClientSpec], Any]],
                key, *, eval_every: int = 5,
                eval_fn: Optional[Callable[[int, float, Params], None]] = None,
-               ckpt_path: Optional[str] = None, mesh=None
+               ckpt_path: Optional[str] = None, mesh=None,
+               on_round: Optional[Callable[[int, jax.Array], None]] = None
                ) -> Tuple[Params, List[float]]:
     """Drive R resident rounds; unflatten only at eval/checkpoint boundaries.
 
@@ -481,7 +486,9 @@ def run_rounds(global_params: Params, cfg: ArchConfig, fl: FLConfig,
     (every ``eval_every`` rounds including r = 0, plus the final round;
     ``eval_every <= 0``: final round only); with ckpt_path set, a
     checkpoint is written from the resident buffer at the same boundaries
-    (``checkpoint.save_from_buffer``).
+    (``checkpoint.save_from_buffer``).  on_round(r, loss) runs right after
+    round r is dispatched, with its loss still on the device (a caller
+    that times rounds blocks on it there).
     Returns (final params tree, per-round mean losses).  ``rounds <= 0``
     returns the input params untouched without flattening or compiling
     anything, so scripted sweeps can no-op cleanly.
@@ -505,6 +512,8 @@ def run_rounds(global_params: Params, cfg: ArchConfig, fl: FLConfig,
         specs, batches = data_fn(r)
         g_buf, loss = driver.round(g_buf, specs, batches,
                                    jax.random.fold_in(key, r))
+        if on_round is not None:
+            on_round(r, loss)
         if pending_loss is not None:
             losses.append(float(pending_loss))
         pending_loss = loss

@@ -5,25 +5,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import use_pallas
 from repro.kernels.fedfa_agg import ref
 from repro.kernels.fedfa_agg.kernel import (quant_accum, scaled_accum,
                                             trimmed_sumsq)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def trimmed_norm(w_flat: jax.Array, thresh: jax.Array, *,
                  use_kernel=None, interpret=False) -> jax.Array:
     """sqrt(Σ w²·[|w|<=t]) over a flat vector, any length (zero-padded)."""
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if not (use_kernel or interpret):
+    if not use_pallas(use_kernel, interpret):
         return jnp.sqrt(ref.trimmed_sumsq_ref(w_flat, thresh))
     lanes = 128
     n = w_flat.size
@@ -34,7 +28,7 @@ def trimmed_norm(w_flat: jax.Array, thresh: jax.Array, *,
     w2 = jnp.zeros((rows_p * lanes,), w_flat.dtype).at[:n].set(w_flat)
     # padding zeros pass |0|<=t -> contribute 0 to the sum: safe.
     ss = trimmed_sumsq(w2.reshape(rows_p, lanes), thresh, block=block,
-                       interpret=interpret or not _on_tpu())
+                       interpret=interpret)
     return jnp.sqrt(ss)
 
 
@@ -42,15 +36,14 @@ def _accum_local(x: jax.Array, weights: jax.Array, mask: jax.Array,
                  use_kernel: bool, interpret: bool) -> jax.Array:
     """The unsharded accumulate body: Σ_c weights[c]·x[c]·mask on whatever
     slice of the client axis this device holds."""
-    if not (use_kernel or interpret):
+    if not use_kernel:
         return ref.scaled_accum_ref(x, weights, mask)
     m, n = x.shape
     block = 4096 if n >= 4096 else max(128, 1 << (n - 1).bit_length())
     pad = (-n) % block
     xp = jnp.pad(x, ((0, 0), (0, pad)))
     mp = jnp.pad(mask, (0, pad))
-    out = scaled_accum(xp, weights, mp, block=block,
-                       interpret=interpret or not _on_tpu())
+    out = scaled_accum(xp, weights, mp, block=block, interpret=interpret)
     return out[:n]
 
 
@@ -61,7 +54,7 @@ def _quant_accum_local(x: jax.Array, weights: jax.Array, wtab: jax.Array,
     folds into the (m, S) table before the kernel, so the quantized rows
     are consumed by exactly one pass."""
     wt = wtab.astype(jnp.float32) * weights.astype(jnp.float32)[:, None]
-    if not (use_kernel or interpret):
+    if not use_kernel:
         return ref.quant_accum_ref(x, wt, seg, mask)
     m, n = x.shape
     block = 4096 if n >= 4096 else max(128, 1 << (n - 1).bit_length())
@@ -69,8 +62,7 @@ def _quant_accum_local(x: jax.Array, weights: jax.Array, wtab: jax.Array,
     xp = jnp.pad(x, ((0, 0), (0, pad)))
     sp = jnp.pad(seg, (0, pad), constant_values=-1)
     mp = jnp.pad(mask, (0, pad))
-    out = quant_accum(xp, wt, sp, mp, block=block,
-                      interpret=interpret or not _on_tpu())
+    out = quant_accum(xp, wt, sp, mp, block=block, interpret=interpret)
     return out[:n]
 
 
@@ -157,8 +149,7 @@ def accumulate(x: jax.Array, weights: jax.Array, mask: jax.Array, *,
     """
     from repro.sharding.cohort import (DATA_AXIS, MODEL_AXIS, model_shards,
                                        shardable)
-    if use_kernel is None:
-        use_kernel = _on_tpu()
+    use_kernel = use_pallas(use_kernel, interpret)
     if not shardable(mesh, x.shape[0]):
         return _accum_local(x, weights, mask, use_kernel, interpret)
     mo = model_shards(mesh)
@@ -170,10 +161,10 @@ def accumulate(x: jax.Array, weights: jax.Array, mask: jax.Array, *,
             part = _accum_local(xs, ws, msk, use_kernel, interpret)
             return jax.lax.psum(part, DATA_AXIS)
 
-        return shard_map(_shard2, mesh=mesh,
+        return jax.shard_map(_shard2, mesh=mesh,
                          in_specs=(P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS),
                                    P(MODEL_AXIS)),
-                         out_specs=P(MODEL_AXIS), check_rep=False)(
+                         out_specs=P(MODEL_AXIS), check_vma=False)(
                              x, weights, mask)
 
     def _shard(xs, ws, ms):
@@ -187,9 +178,9 @@ def accumulate(x: jax.Array, weights: jax.Array, mask: jax.Array, *,
         return jax.lax.psum(part, DATA_AXIS)
 
     out_spec = P(MODEL_AXIS) if mo > 1 else P(None)
-    return shard_map(_shard, mesh=mesh,
+    return jax.shard_map(_shard, mesh=mesh,
                      in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(None)),
-                     out_specs=out_spec, check_rep=False)(x, weights, mask)
+                     out_specs=out_spec, check_vma=False)(x, weights, mask)
 
 
 @functools.partial(jax.jit,
@@ -217,8 +208,7 @@ def accumulate_quant(x: jax.Array, weights: jax.Array, wtab: jax.Array,
     """
     from repro.sharding.cohort import (DATA_AXIS, MODEL_AXIS, model_shards,
                                        shardable)
-    if use_kernel is None:
-        use_kernel = _on_tpu()
+    use_kernel = use_pallas(use_kernel, interpret)
     if not shardable(mesh, x.shape[0]):
         return _quant_accum_local(x, weights, wtab, seg, mask,
                                   use_kernel, interpret)
@@ -233,11 +223,11 @@ def accumulate_quant(x: jax.Array, weights: jax.Array, wtab: jax.Array,
                                       use_kernel, interpret)
             return jax.lax.psum(part, DATA_AXIS)
 
-        return shard_map(_shard2, mesh=mesh,
+        return jax.shard_map(_shard2, mesh=mesh,
                          in_specs=(P(DATA_AXIS, MODEL_AXIS), P(DATA_AXIS),
                                    P(DATA_AXIS, None), P(None, MODEL_AXIS),
                                    P(MODEL_AXIS)),
-                         out_specs=P(MODEL_AXIS), check_rep=False)(
+                         out_specs=P(MODEL_AXIS), check_vma=False)(
                              x, weights, wtab, seg2, mask)
 
     def _shard(xs, ws, wt, sg, msk):
@@ -252,8 +242,8 @@ def accumulate_quant(x: jax.Array, weights: jax.Array, wtab: jax.Array,
         return jax.lax.psum(part, DATA_AXIS)
 
     out_spec = P(MODEL_AXIS) if mo > 1 else P(None)
-    return shard_map(_shard, mesh=mesh,
+    return jax.shard_map(_shard, mesh=mesh,
                      in_specs=(P(DATA_AXIS, None), P(DATA_AXIS),
                                P(DATA_AXIS, None), P(None, None), P(None)),
-                     out_specs=out_spec, check_rep=False)(
+                     out_specs=out_spec, check_vma=False)(
                          x, weights, wtab, seg2, mask)

@@ -43,87 +43,133 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BINS = 256          # one byte per level: 4 levels cover the 32-bit pattern
 _LEVELS = 4
 _PATHS = 2           # floor and ceil ranks bracketing the quantile position
 TILE = 512          # column tile (lane-aligned); callers pad cols to this
+_ROW_TILE = 8        # sublane tile: row blocks are 8 rows or the whole axis
 
 
 def _hist_level_kernel(shift_ref, hi_ref, x_ref, seg_ref, sc_ref, cnt_ref,
                        sq_ref):
-    """One refinement level: per-(client, path, segment) histogram planes.
+    """One refinement level: per-(row, path, bin, segment) histogram planes.
 
-    shift_ref (1, 1) i32: the level's bit shift (24, 16, 8, 0).
-    hi_ref (m, P, S) i32: expected resolved prefix ``lo >> (shift+8)``.
-    x_ref (m, T) column tile (f32, or the quantized admission dtype);
+    shift_ref (1, 1) i32 in SMEM: the level's bit shift (24, 16, 8, 0).
+    hi_ref (rb*P, S) i32: expected resolved prefix ``lo >> (shift+8)``,
+    rows ordered (row, path).
+    x_ref (rb, T) column tile (f32, or the quantized admission dtype);
     seg_ref (1, T) i32 segment ids (-1 = pad).
-    sc_ref (m, S) f32 per-(client, segment) dequant scales: the byte walk
+    sc_ref (rb, S) f32 per-(row, segment) dequant scales: the byte walk
     bins DEQUANTIZED magnitudes — the scale is gathered per column through
     the same segment one-hot the histograms use (all-ones on the f32 path,
     where the multiply is exact).
-    cnt_ref (m, P, S, B) i32 / sq_ref (m, P, S, B) f32: accumulated over the
-    column grid (zeroed on the first tile, += on revisits).
+    cnt_ref (rb, P, B, S) i32 / sq_ref (rb, P, B, S) f32: accumulated over
+    the column grid axis (zeroed on the first tile, += on revisits).
+
+    Every value stays 2-D with the column tile on the lanes, and the
+    segment one-hot is (S, T), so the per-column gathers are (r, S) @ (S, T)
+    matmuls and the histogram update is a (B, T) x (S, T) contraction over
+    the lanes — the shapes Mosaic lays out natively.  One segment (the row
+    path) needs no one-hot: gathers broadcast and updates lane-reduce.
+    Gathers and Σx² run at HIGHEST precision: the one-hot selects exactly
+    one f32 value (prefixes < 2^24 are exact), and the Σx² planes keep f32
+    accuracy instead of the MXU's default bf16 pass.
     """
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
         sq_ref[...] = jnp.zeros_like(sq_ref)
 
     shift = shift_ref[0, 0]
     hs = jnp.minimum(shift + 8, 31)      # bit 31 of |x| patterns is 0
-    m, T = x_ref.shape
-    _, P, S, B = cnt_ref.shape
-    seg = seg_ref[0, :]                                       # (T,)
+    rb, T = x_ref.shape
+    _, P, B, S = cnt_ref.shape
+    seg = seg_ref[...]                                        # (1, T)
     valid = seg >= 0
-    seg_oh = jnp.where(
-        valid[:, None],
-        (seg[:, None] == jax.lax.broadcasted_iota(jnp.int32, (T, S), 1))
-        .astype(jnp.float32),
-        0.0)                                                  # (T, S)
-    # scales are nonnegative, so |x·scale| = |x|·scale; inert columns get
-    # scale 0 but are excluded from every histogram by seg_oh anyway
-    scl = jax.lax.dot_general(
-        sc_ref[...].astype(jnp.float32), seg_oh,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # (m, T)
-    x = jnp.abs(x_ref[...].astype(jnp.float32) * scl)         # (m, T)
-    bits = jax.lax.bitcast_convert_type(x, jnp.int32)         # monotone
-    binv = jax.lax.shift_right_logical(bits, shift) & (B - 1)
-    hi = jax.lax.shift_right_logical(bits, hs)                # < 2^24
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (T, B), 1)
-    for c in range(m):
-        x2 = x[c] * x[c]
+    hp = jax.lax.Precision.HIGHEST
+    if S == 1:
+        def gather(tab):                                      # (r, 1) -> (r, T)
+            return jnp.broadcast_to(tab, (tab.shape[0], T))
+
+        def update(a):                                        # (B, T) -> (B, 1)
+            return jnp.sum(a, axis=1, keepdims=True)
+    else:
+        seg_oh = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (S, T), 0) == seg,
+            1.0, 0.0)                                         # (S, T)
+
+        def gather(tab):                                      # (r, S) -> (r, T)
+            return jnp.dot(tab, seg_oh, precision=hp,
+                           preferred_element_type=jnp.float32)
+
+        def update(a):                                        # (B, T) -> (B, S)
+            return jax.lax.dot_general(
+                a, seg_oh, (((1,), (1,)), ((), ())), precision=hp,
+                preferred_element_type=jnp.float32)
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, T), 0)
+    for c in range(rb):
+        # scales are nonnegative, so |x·scale| = |x|·scale; inert columns
+        # are excluded from every histogram by ``valid``
+        scl = gather(sc_ref[c:c + 1, :].astype(jnp.float32))  # (1, T)
+        x = jnp.abs(x_ref[c:c + 1, :].astype(jnp.float32) * scl)
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)     # monotone
+        binv = jax.lax.shift_right_logical(bits, shift) & (B - 1)
+        hi = jax.lax.shift_right_logical(bits, hs)            # < 2^24
+        hit = jnp.where(iota_b == binv, 1.0, 0.0)             # (B, T)
+        x2 = x * x
         for p in range(P):
-            # expected prefix per column via exact f32 one-hot gather
-            hi_e = jnp.dot(seg_oh, hi_ref[c, p].astype(jnp.float32))
-            inb = (hi[c] == hi_e.astype(jnp.int32)) & valid   # (T,)
-            bin_oh = jnp.where(
-                inb[:, None] & (iota_b == binv[c][:, None]), 1.0, 0.0)
-            cnt_ref[c, p] += jnp.dot(seg_oh.T, bin_oh).astype(jnp.int32)
-            sq_ref[c, p] += jnp.dot(seg_oh.T, bin_oh * x2[:, None])
+            r = c * P + p
+            hi_e = gather(hi_ref[r:r + 1, :].astype(jnp.float32))
+            inb = jnp.where((hi == hi_e.astype(jnp.int32)) & valid,
+                            1.0, 0.0)                         # (1, T)
+            bin_oh = hit * inb                                # (B, T)
+            cnt_ref[c, p] += update(bin_oh).astype(jnp.int32)
+            sq_ref[c, p] += update(bin_oh * x2)
+
+
+def _row_block(m: int) -> int:
+    """Rows per kernel block: the whole row axis up to the 8-sublane tile,
+    else one tile (callers pad the row axis to a multiple of it)."""
+    return m if m <= _ROW_TILE else _ROW_TILE
 
 
 def _hist_call(x, seg_id, sc, hi, shift, *, interpret: bool):
+    """One level's histogram planes (m, P, S, B) over the (m, C) slice.
+    m must be at most 8 or a multiple of 8, and C a multiple of the tile."""
     m, C = x.shape
     _, P, S = hi.shape
     T = min(C, TILE)
-    assert C % T == 0
-    out_shape = [jax.ShapeDtypeStruct((m, P, S, _BINS), jnp.int32),
-                 jax.ShapeDtypeStruct((m, P, S, _BINS), jnp.float32)]
-    return pl.pallas_call(
+    rb = _row_block(m)
+    assert C % T == 0 and m % rb == 0
+    out_shape = [jax.ShapeDtypeStruct((m, P, _BINS, S), jnp.int32),
+                 jax.ShapeDtypeStruct((m, P, _BINS, S), jnp.float32)]
+    out_block = pl.BlockSpec((rb, P, _BINS, S), lambda i, j: (i, 0, 0, 0))
+    # both planes stay resident across the column axis (x2 for the
+    # pipeline's buffers), next to the double-buffered input tiles and the
+    # (B, T) / (S, T) one-hots of the body
+    resident = 2 * 2 * rb * P * _BINS * S * 4
+    tiles = 2 * 4 * (rb * T + T + rb * S + rb * P * S)
+    body = 4 * T * (4 * _BINS + 2 * S + 8 * rb)
+    vmem = min(resident + tiles + body + (8 << 20), 100 << 20)
+    cnt, sq = pl.pallas_call(
         _hist_level_kernel,
-        grid=(C // T,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((m, P, S), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((m, T), lambda i: (0, i)),
-                  pl.BlockSpec((1, T), lambda i: (0, i)),
-                  pl.BlockSpec((m, S), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((m, P, S, _BINS), lambda i: (0, 0, 0, 0)),
-                   pl.BlockSpec((m, P, S, _BINS), lambda i: (0, 0, 0, 0))],
+        grid=(m // rb, C // T),
+        in_specs=[pl.BlockSpec((1, 1), lambda i, j: (0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((rb * P, S), lambda i, j: (i, 0)),
+                  pl.BlockSpec((rb, T), lambda i, j: (i, j)),
+                  pl.BlockSpec((1, T), lambda i, j: (0, j)),
+                  pl.BlockSpec((rb, S), lambda i, j: (i, 0))],
+        out_specs=[out_block, out_block],
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(shift.reshape(1, 1), hi, x, seg_id.reshape(1, C), sc)
+    )(shift.reshape(1, 1), hi.reshape(m * P, S), x, seg_id.reshape(1, C), sc)
+    return jnp.swapaxes(cnt, 2, 3), jnp.swapaxes(sq, 2, 3)
 
 
 def segmented_trimmed_stats(x, seg_id, seg_len, q_seg, *, scales=None,
@@ -157,6 +203,13 @@ def segmented_trimmed_stats(x, seg_id, seg_len, q_seg, *, scales=None,
         sc = jnp.ones((m, S), jnp.float32)
     else:
         sc = scales.astype(jnp.float32)
+    m_real = m
+    pad = (-m) % _row_block(m)
+    if pad:      # whole sublane tiles: zero rows at q = 1, sliced off below
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        sc = jnp.pad(sc, ((0, pad), (0, 0)), constant_values=1.0)
+        q_seg = jnp.pad(q_seg, ((0, pad), (0, 0)), constant_values=1.0)
+        m += pad
     seg_id = seg_id.astype(jnp.int32)
     nseg = seg_len.astype(jnp.int32)
     p = q_seg.astype(jnp.float32) * (nseg - 1).astype(jnp.float32)[None, :]
@@ -200,7 +253,7 @@ def segmented_trimmed_stats(x, seg_id, seg_len, q_seg, *, scales=None,
     t = v0 * (1.0 - frac) + v1 * frac
     # no data value lies strictly between adjacent order statistics
     ss = jnp.where(t < v1, sqb[:, 0], sqb[:, 1])
-    return t, ss
+    return t[:m_real], ss[:m_real]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -215,19 +268,24 @@ def row_trimmed_stats_multilevel(rows, q, *, scale=None,
     """
     R, L = rows.shape
     Cp = -(-L // TILE) * TILE
+    Rp = R + (-R) % _row_block(R)
     if scale is None:
         rows = rows.astype(jnp.float32)
-    if Cp != L:
-        rows = jnp.zeros((R, Cp), rows.dtype).at[:, :L].set(rows)
+    if (Rp, Cp) != (R, L):     # one staging copy pads both axes
+        rows = jnp.zeros((Rp, Cp), rows.dtype).at[:R, :L].set(rows)
+        q = jnp.ones((Rp,), jnp.float32).at[:R].set(q.astype(jnp.float32))
+        if scale is not None:
+            scale = jnp.ones((Rp,), jnp.float32).at[:R].set(
+                scale.astype(jnp.float32))
     col = jax.lax.iota(jnp.int32, Cp)
     seg_id = jnp.where(col < L, 0, -1)
     seg_len = jnp.full((1,), L, jnp.int32)
     t, ss = segmented_trimmed_stats(
-        rows, seg_id, seg_len, q.reshape(R, 1).astype(jnp.float32),
+        rows, seg_id, seg_len, q.reshape(Rp, 1).astype(jnp.float32),
         scales=None if scale is None else
-        scale.reshape(R, 1).astype(jnp.float32),
+        scale.reshape(Rp, 1).astype(jnp.float32),
         interpret=interpret)
-    return t[:, 0], ss[:, 0]
+    return t[:R, 0], ss[:R, 0]
 
 
 def histogram_elems(rows: int, segs: int) -> int:

@@ -6,24 +6,29 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.kernels.fedfa_quantile import multilevel, ref
 from repro.kernels.fedfa_quantile.kernel import quantile_fused
 
 _LANES = 128
-_BLOCK_ROWS = 8
-# Per-invocation element budget for the SINGLE-PASS kernel only: it holds
-# the f32 block, its int32 bit view and a few same-shaped intermediates in
-# VMEM (~16B/element), so 2^18 elements keeps a block under ~4 MiB of the
-# ~16 MiB/core budget.  block_rows shrinks as rows get longer to stay
-# inside it; rows longer than the whole budget dispatch to the two-stage
-# multilevel kernel (still read-once, still sort-free) — NEVER to the jnp
-# oracle.  The oracle runs only when the caller explicitly deselects the
-# kernel path (use_kernel=False without interpret).
+_BLOCK_ROWS = 8      # the sublane tile: a block is 8 rows or the whole axis
+# Row length past which the SINGLE-PASS kernel hands over: one 8-row block
+# holds the f32 rows, their int32 bit view and a few same-shaped
+# intermediates in VMEM (~16B/element), so rows of up to 2^18 elements keep
+# a block near 32 MiB of the chip's 128 MiB.  Longer rows dispatch to the
+# two-stage multilevel kernel (still read-once, still sort-free) — NEVER to
+# the jnp oracle.  The oracle runs only when the caller explicitly
+# deselects the kernel path (use_kernel=False without interpret).
 _SINGLE_PASS_ELEMS = 1 << 18
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def single_pass_block(R: int, L: int):
+    """(block_rows, padded rows, padded length) of the single-pass dispatch
+    for (R, L) rows: lanes pad to 128, and a block is the whole row axis
+    below 8 rows, else 8 rows — the TPU refuses any other sublane count."""
+    Lp = -(-L // _LANES) * _LANES
+    rb = min(_BLOCK_ROWS, R)
+    return rb, -(-R // rb) * rb, Lp
 
 
 def fused_quantile_contract(block_bytes=None, *, padded: bool = False):
@@ -93,22 +98,19 @@ def row_trimmed_stats(rows: jax.Array, q: jax.Array, *,
     Dispatch: rows that fit one VMEM block go to the single-pass kernel;
     longer rows (embedding-scale leaves) go to the two-stage multilevel
     kernel.  Both are read-once and sort-free; the jnp oracle runs ONLY
-    when the caller explicitly deselects the kernel path.
+    when the kernel path is deselected (``use_kernel=False``, or None off
+    a TPU).  ``use_kernel=True`` off a TPU raises unless ``interpret``.
     """
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     R, L = rows.shape
-    if not (use_kernel or interpret):
+    if not use_pallas(use_kernel, interpret):
         if scale is not None:
             rows = rows.astype(jnp.float32) \
                 * scale[:, None].astype(jnp.float32)
         return ref.row_trimmed_stats_ref(rows, q)
-    Lp = ((L + _LANES - 1) // _LANES) * _LANES
+    rb, Rp, Lp = single_pass_block(R, L)
     if Lp > _SINGLE_PASS_ELEMS:
         return multilevel.row_trimmed_stats_multilevel(
-            rows, q, scale=scale, interpret=interpret or not _on_tpu())
-    rb = max(1, min(_BLOCK_ROWS, R, _SINGLE_PASS_ELEMS // Lp))
-    Rp = ((R + rb - 1) // rb) * rb
+            rows, q, scale=scale, interpret=interpret)
     want = rows.dtype if scale is not None else jnp.float32
     if Lp == L and Rp == R:
         rows_p, q_p = rows.astype(want), q.astype(jnp.float32)
@@ -122,5 +124,5 @@ def row_trimmed_stats(rows: jax.Array, q: jax.Array, *,
             jnp.ones((Rp,), jnp.float32).at[:R].set(
                 scale.astype(jnp.float32))
     t, ss = quantile_fused(rows_p, q_p, L=L, block_rows=rb, scale=s_p,
-                           interpret=interpret or not _on_tpu())
+                           interpret=interpret)
     return t[:R], ss[:R]

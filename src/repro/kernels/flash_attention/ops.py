@@ -1,7 +1,7 @@
 """jit'd wrapper: pads to tile/lane boundaries, dispatches kernel vs oracle.
 
-On TPU the Pallas kernel is the default; elsewhere (this CPU container) the
-oracle runs and the kernel is exercised in interpret mode by tests.
+On TPU the Pallas kernel is the default; elsewhere the oracle runs, and
+tests exercise the kernel with ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -11,12 +11,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.kernel import flash_attention
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -36,9 +33,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
               use_kernel: Optional[bool] = None,
               interpret: bool = False) -> jax.Array:
     """Public entry point; q (B,Sq,H,hd), k/v (B,Sk,K,hd)."""
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if not use_kernel and not interpret:
+    if not use_pallas(use_kernel, interpret):
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     B, Sq, H, hd = q.shape
     bq = min(bq, max(8, 1 << (Sq - 1).bit_length()))

@@ -7,12 +7,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas
 from repro.kernels.ssd import ref
 from repro.kernels.ssd.kernel import ssd_intra_chunk
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "use_kernel", "interpret"))
@@ -21,8 +18,6 @@ def ssd(x, dt, A, B, C, chunk: int, *, use_kernel=None, interpret=False
     """Full chunked SSD matching repro.models.ssm.ssd_chunked_ref.
     x: (b,S,nh,hp); dt: (b,S,nh); A: (nh,); B,C: (b,S,N).
     Returns (y (b,S,nh,hp), final_state (b,nh,hp,N))."""
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     b, S, nh, hp = x.shape
     N = B.shape[-1]
     Q = chunk
@@ -39,9 +34,9 @@ def ssd(x, dt, A, B, C, chunk: int, *, use_kernel=None, interpret=False
     Bg = B.reshape(b * nc, Q, N)
     Cg = C.reshape(b * nc, Q, N)
 
-    if use_kernel or interpret:
+    if use_pallas(use_kernel, interpret):
         y_intra, state, L = ssd_intra_chunk(xg, dtg, A, Bg, Cg,
-                                            interpret=interpret or not _on_tpu())
+                                            interpret=interpret)
     else:
         y_intra, state, L = ref.ssd_intra_chunk_ref(xg, dtg, A, Bg, Cg)
 

@@ -27,7 +27,11 @@ def _validated_mesh(shape, axes):
             f"(jax.device_count() == {have}); pick a shape whose product is "
             f"<= {have} or launch with more devices "
             f"(XLA_FLAGS=--xla_force_host_platform_device_count=K on CPU)")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the round places its arrays with NamedShardings and
+    # with_sharding_constraint, which Explicit axes (make_mesh's default)
+    # refuse
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,20 +3,21 @@
 Two modes:
   * ``--mode fl``     — the paper's workload: synthetic federated rounds with
     heterogeneous client architectures, FedFA (or baseline) aggregation,
-    optional backdoor attackers.  This is what examples/ and benchmarks/
-    drive at CPU scale.
+    optional backdoor attackers, at the architecture's published widths
+    (``--reduced``: the CPU-sized preset that examples/ and benchmarks/
+    drive).
   * ``--mode dense``  — plain distributed pretraining of one architecture
     (the e2e driver for (b): train a ~100M model for a few hundred steps).
 
-For multi-host production the same functions are jitted with the meshes
-from repro.launch.mesh; on this container they run on CPU with a host mesh.
+The round runs on the default JAX backend; ``--mesh`` shards it over the
+meshes from repro.launch.mesh.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -65,6 +66,51 @@ def run_dense(arch: str, steps: int, batch: int, seq_len: int,
             "last": float(np.mean(losses[-5:]))}
 
 
+def _make_accuracy(cfg, task: str, n_classes: int, chunk: int = 16):
+    """Jitted eval: accuracy(params, tokens, labels, masks=, gates=,
+    class_mask=) -> float.  ``cls`` scores the mean-pooled class logits
+    against the labels; ``lm`` scores next-token predictions.  Width masks
+    extract a client's sub-model.  Sequences run ``chunk`` at a time, so
+    the (chunk, S, vocab) logits are all the eval holds beside the
+    training state at published widths."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.masking import apply_mask_tree, axis_mask_tree
+    from repro.models import model as model_mod
+
+    @jax.jit
+    def hits(p, tokens, labels, masks, gates, class_mask):
+        if masks is not None:
+            p = apply_mask_tree(p, axis_mask_tree(cfg, masks))
+
+        def one(xs):
+            tok, lab = xs
+            logits, _ = model_mod.forward(p, cfg, {"tokens": tok},
+                                          masks=masks, gates=gates,
+                                          remat=False)
+            if task == "lm":
+                lg, tgt = logits[:, :-1], tok[:, 1:]
+                cm = class_mask
+            else:
+                lg, tgt = jnp.mean(logits[..., :n_classes], axis=1), lab
+                cm = None if class_mask is None else class_mask[:n_classes]
+            if cm is not None:
+                lg = jnp.where(cm > 0, lg, -1e30)
+            return jnp.sum((jnp.argmax(lg, -1) == tgt).astype(jnp.float32))
+
+        n = tokens.shape[0] // chunk
+        return jnp.sum(jax.lax.map(one, (
+            tokens.reshape(n, chunk, *tokens.shape[1:]),
+            labels.reshape(n, chunk, *labels.shape[1:]))))
+
+    def accuracy(p, tokens, labels, masks=None, gates=None, class_mask=None):
+        per_seq = tokens.shape[1] - 1 if task == "lm" else 1
+        h = hits(p, jnp.asarray(tokens), jnp.asarray(labels), masks, gates,
+                 class_mask)
+        return float(h) / (tokens.shape[0] * per_seq)
+    return accuracy
+
+
 def client_arch_pool(cfg, mode: str, fracs=(0.25, 0.5, 0.75, 1.0)):
     """Paper's three flexibility regimes: depth-only (vs FlexiFed),
     width-only (vs HeteroFL), both (vs NeFL)."""
@@ -95,8 +141,21 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
            mesh: Optional[str] = None,
            use_kernel: Optional[bool] = None,
            interpret: bool = False, update_dtype: str = "f32",
-           ckpt: Optional[str] = None,
+           ckpt: Optional[str] = None, reduced: bool = False,
+           on_round: Optional[Callable] = None,
            quiet: bool = False) -> dict:
+    """Federated training of the registered ``arch`` at its published
+    widths.  ``reduced=True`` takes the CPU-sized preset instead:
+    ``ArchConfig.reduced()`` with 4 layers in 2 sections, so depth
+    flexibility stays real.  The ``cls`` task replaces the vocabulary with
+    its class slots; ``lm`` keeps the published vocabulary.
+
+    Driver, engine, admission dtype and mesh combine only where the driver
+    supports them; any other combination raises rather than running a
+    configuration that was not asked for.  ``on_round(r, loss)`` (resident
+    driver) runs after each round is dispatched, with the round's loss
+    still on the device.  Returns the eval history plus ``round_loss``,
+    every round's (async: every merge's) mean client loss."""
     import jax
     import jax.numpy as jnp
     from repro.configs import get_arch
@@ -107,10 +166,23 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
     from repro.models import model as model_mod
     from repro.models.masks import ClientArch, max_section_depths
 
-    cfg = get_arch(arch).reduced().replace(n_layers=4, n_sections=2)
-    # 4 layers / 2 sections so DEPTH flexibility is real (reduced() alone
-    # gives 2 layers -> both sections have max depth 1 and the depth pool
-    # degenerates to homogeneous clients).
+    if driver in ("resident", "async") and agg_engine != "flat":
+        raise ValueError(f"the {driver} driver runs the flat engine only; "
+                         f"agg_engine={agg_engine!r} needs driver='per-round'")
+    if update_dtype != "f32" and driver == "per-round":
+        raise ValueError(f"update_dtype={update_dtype!r} needs a resident "
+                         "cohort state: driver='resident' or 'async'")
+    if mesh not in (None, "none") and driver == "per-round":
+        raise ValueError("a mesh shards the resident/async drivers' cohort "
+                         "axis; the per-round driver runs unsharded "
+                         "(mesh='none')")
+
+    cfg = get_arch(arch)
+    if reduced:
+        # 4 layers / 2 sections so DEPTH flexibility is real (reduced()
+        # alone gives 2 layers -> both sections have max depth 1 and the
+        # depth pool degenerates to homogeneous clients)
+        cfg = cfg.reduced().replace(n_layers=4, n_sections=2)
     if task == "cls":
         cfg = cfg.replace(vocab_size=max(64, n_classes), tie_embeddings=False)
     key = jax.random.PRNGKey(seed)
@@ -134,41 +206,26 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
     hist = {"round": [], "loss": [], "global_acc": [], "local_acc": []}
     test = pipeline.eval_batch_cls(n_classes, cfg.vocab_size, 256, seq_len,
                                    profiles, seed=seed + 99)
-    test_j = {k: jnp.asarray(v) for k, v in test.items()}
-
-    @jax.jit
-    def global_acc(p):
-        logits, _ = model_mod.forward(p, cfg, {"tokens": test_j["tokens"]},
-                                      remat=False)
-        pred = jnp.argmax(jnp.mean(logits[..., :n_classes], axis=1), -1)
-        return jnp.mean((pred == test_j["labels"]).astype(jnp.float32))
+    accuracy = _make_accuracy(cfg, task, n_classes)
 
     # local personalization metric (non-IID): extracted client models on
     # class-restricted local test sets (paper's "average local accuracy")
-    from repro.core.masking import apply_mask_tree, axis_mask_tree
     local_eval = []
     for ci in range(min(4, n_clients)):
         d = pipeline.eval_batch_cls(n_classes, cfg.vocab_size, 64, seq_len,
                                     profiles, classes=parts[ci]["classes"],
                                     seed=seed + 300 + ci)
-        local_eval.append((ci, {k: jnp.asarray(v) for k, v in d.items()}))
+        s = specs[ci]
+        cm = None if s.class_mask is None else jnp.asarray(s.class_mask)
+        local_eval.append((s.arch.masks(cfg), s.arch.gates(cfg), cm, d))
+
+    def global_acc(p):
+        return accuracy(p, test["tokens"], test["labels"])
 
     def local_acc(p):
-        accs = []
-        for ci, d in local_eval:
-            s = specs[ci]
-            masks = s.arch.masks(cfg)
-            gates = s.arch.gates(cfg)
-            pm = apply_mask_tree(p, axis_mask_tree(cfg, masks))
-            logits, _ = model_mod.forward(pm, cfg, {"tokens": d["tokens"]},
-                                          masks=masks, gates=gates, remat=False)
-            lg = jnp.mean(logits[..., :n_classes], axis=1)
-            if s.class_mask is not None:
-                cm = jnp.asarray(s.class_mask[:n_classes])
-                lg = jnp.where(cm[None] > 0, lg, -1e30)
-            accs.append(float(jnp.mean(
-                (jnp.argmax(lg, -1) == d["labels"]).astype(jnp.float32))))
-        return float(np.mean(accs))
+        return float(np.mean([accuracy(p, d["tokens"], d["labels"], masks=mk,
+                                       gates=gt, class_mask=cm)
+                              for mk, gt, cm, d in local_eval]))
 
     def round_data(r):
         """Host-side per-round cohort selection + batch synthesis (shared by
@@ -182,7 +239,7 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
                 {k: jnp.asarray(v) for k, v in batches_np.items()})
 
     def record_eval(r, loss, p):
-        acc = float(global_acc(p))
+        acc = global_acc(p)
         lacc = local_acc(p)
         hist["round"].append(r)
         hist["loss"].append(loss)
@@ -193,35 +250,15 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
                   f"loss {loss:.4f} global_acc {acc:.3f} "
                   f"local_acc {lacc:.3f}", flush=True)
 
-    if driver in ("resident", "async") and agg_engine != "flat":
-        if not quiet:
-            print(f"{driver} driver is flat-native; falling back to the "
-                  "per-round driver for agg_engine=tree", flush=True)
-        driver = "per-round"
-    if update_dtype != "f32" and driver == "per-round":
-        # quantized admission lives in the resident/async flat programs;
-        # the per-round driver re-dispatches trees and has no cohort pool
-        # to quantize into
-        if not quiet:
-            print(f"--update-dtype {update_dtype} needs the resident or "
-                  "async driver; running the per-round driver at f32",
-                  flush=True)
-        import dataclasses
-        fl = dataclasses.replace(fl, update_dtype="f32")
-
     from repro.launch.mesh import get_mesh
     mesh_obj = get_mesh(mesh)
-    if mesh_obj is not None and driver not in ("resident", "async"):
-        if not quiet:
-            print("--mesh shards the resident/async drivers' cohort axis; "
-                  "the per-round driver runs unsharded", flush=True)
-        mesh_obj = None
 
     if driver == "resident":
         from repro.core.round import run_rounds
-        params, _ = run_rounds(params, cfg, fl, rounds, round_data, key,
-                               eval_every=eval_every, eval_fn=record_eval,
-                               ckpt_path=ckpt, mesh=mesh_obj)
+        params, hist["round_loss"] = run_rounds(
+            params, cfg, fl, rounds, round_data, key, eval_every=eval_every,
+            eval_fn=record_eval, ckpt_path=ckpt, mesh=mesh_obj,
+            on_round=on_round)
     elif driver == "async":
         # continuous arrivals from the trace-driven population simulator:
         # clients keep their round_data specs/batches, but WHEN they arrive
@@ -246,22 +283,25 @@ def run_fl(arch: str, rounds: int, n_clients: int, *, strategy: str = "fedfa",
             capacity=capacity,
             merge_k=merge_k if merge_k > 0 else max(1, capacity // 2),
             staleness_max=staleness_max, deadline=async_deadline)
-        params, _ = run_async(params, cfg, fl, rounds, source, key,
-                              acfg=acfg, eval_every=eval_every,
-                              eval_fn=record_eval, ckpt_path=ckpt,
-                              mesh=mesh_obj)
+        params, hist["round_loss"] = run_async(
+            params, cfg, fl, rounds, source, key, acfg=acfg,
+            eval_every=eval_every, eval_fn=record_eval, ckpt_path=ckpt,
+            mesh=mesh_obj)
     else:
         from repro.checkpoint import checkpoint as ckpt_mod
         from repro.core.round import eval_boundary
+        round_loss = []
         for r in range(rounds):
             sel_specs, batches = round_data(r)
             params, loss = fl_round(params, cfg, fl, sel_specs, batches,
                                     jax.random.fold_in(key, r))
+            round_loss.append(loss)
             if eval_boundary(r, rounds, eval_every):
                 record_eval(r, float(loss), params)
                 if ckpt is not None:
                     ckpt_mod.save(f"{ckpt}_r{r:05d}", params,
                                   meta={"round": r, "strategy": strategy})
+        hist["round_loss"] = [float(x) for x in round_loss]
     # rounds=0 (or eval_every configurations that never fire) leaves the
     # history empty — a scripted sweep no-op, not an IndexError
     hist["final_acc"] = hist["global_acc"][-1] if hist["global_acc"] else None
@@ -323,7 +363,8 @@ def main() -> None:
                          "parameter shards; overrides --mesh")
     ap.add_argument("--use-kernel", choices=["auto", "on", "off"],
                     default="auto",
-                    help="flat engine: Pallas kernel dispatch (auto=TPU only)")
+                    help="flat engine: Pallas kernel dispatch (auto=TPU "
+                         "only; on raises off a TPU unless --interpret)")
     ap.add_argument("--interpret", action="store_true",
                     help="flat engine: run Pallas kernels in interpret mode")
     ap.add_argument("--update-dtype", choices=["f32", "bf16", "int8"],
@@ -334,8 +375,13 @@ def main() -> None:
                          "kernels dequantize in VMEM")
     ap.add_argument("--ckpt", default=None,
                     help="checkpoint path prefix (written at eval boundaries)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="fl mode: the CPU-sized preset (reduced widths, 4 "
+                         "layers) instead of the published widths")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "dense":
         res = run_dense(args.arch, args.steps, args.batch, args.seq_len)
     else:
@@ -356,7 +402,8 @@ def main() -> None:
                      use_kernel={"auto": None, "on": True,
                                  "off": False}[args.use_kernel],
                      interpret=args.interpret,
-                     update_dtype=args.update_dtype, ckpt=args.ckpt)
+                     update_dtype=args.update_dtype, ckpt=args.ckpt,
+                     reduced=args.reduced)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(res, f, indent=1)

@@ -268,7 +268,7 @@ def test_backdoor_robustness_row_int8():
                        malicious_frac=frac, attack_lambda=20.0,
                        local_steps=1, batch=2, seq_len=8,
                        participation=1.0, eval_every=0, seed=0,
-                       update_dtype=dt, quiet=True)
+                       update_dtype=dt, reduced=True, quiet=True)
             assert np.isfinite(h["loss"]).all(), (dt, attack, h["loss"])
             accs[(dt, attack)] = h["final_acc"]
     drop_f32 = accs[("f32", "clean")] - accs[("f32", "attacked")]

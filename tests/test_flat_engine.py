@@ -129,12 +129,14 @@ def test_flat_index_segments_consistent():
     assert index.row_of.max() == index.n_segments - 1
     # segment ids are contiguous leaf-major runs
     assert (np.diff(index.row_of) >= 0).all()
-    # graft metadata: identity off stage 0
-    off_stage0 = index.g_rest == 0
-    idx = np.arange(index.n)
-    assert (index.g_base[off_stage0] == idx[off_stage0]).all()
-    # stage-0 leaves exist in this config and carry row/rest info
-    assert (~off_stage0).any() and index.seg_stage0.any()
+    # grafting is the identity off stage 0: a reversed row map moves only
+    # the stage-0 segments, and those exist in this config
+    stage0 = index.seg_stage0[index.row_of]
+    buf = jnp.arange(index.n, dtype=jnp.float32)
+    gmap = jnp.arange(int(index.seg_row[index.seg_stage0].max()) + 1)[::-1]
+    moved = np.asarray(flat._graft_flat(index, buf, gmap)) != np.asarray(buf)
+    assert not moved[~stage0].any()
+    assert moved[stage0].any() and (~stage0).any()
 
 
 def test_flat_graft_matches_tree_graft():

@@ -90,6 +90,7 @@ def test_fl_converges_on_classification():
     from repro.launch.train import run_fl
     hist = run_fl("smollm-135m", rounds=6, n_clients=8, strategy="fedfa",
                   local_steps=2, batch=4, seq_len=32, lr=0.05,
-                  participation=0.5, eval_every=5, seed=0)
+                  participation=0.5, eval_every=5, seed=0,
+                  reduced=True)
     assert hist["global_acc"][-1] > hist["global_acc"][0] + 0.1
     assert hist["final_acc"] > 0.35

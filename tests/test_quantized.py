@@ -196,14 +196,13 @@ def test_trimmed_stats_scale_matches_dequant_oracle():
                                rtol=5e-3, atol=1e-5)
 
 
-def test_per_round_driver_falls_back_to_f32(capsys):
+def test_per_round_driver_falls_back_to_f32():
     """``--update-dtype`` needs a resident cohort state; the per-round
-    driver has none, so run_fl downgrades to f32 with a notice instead of
-    crashing mid-run."""
+    driver has none, so run_fl refuses the combination up front instead of
+    quietly running an f32 round that was not asked for."""
     from repro.launch.train import run_fl
 
-    hist = run_fl("smollm-135m", 1, 2, driver="per-round",
-                  update_dtype="int8", local_steps=1, batch=2, seq_len=8,
-                  participation=1.0, eval_every=0)
-    assert np.isfinite(hist["loss"]).all()
-    assert "f32" in capsys.readouterr().out
+    with pytest.raises(ValueError, match="resident cohort state"):
+        run_fl("smollm-135m", 1, 2, driver="per-round", update_dtype="int8",
+               local_steps=1, batch=2, seq_len=8, participation=1.0,
+               eval_every=0, reduced=True)

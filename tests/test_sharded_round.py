@@ -211,7 +211,7 @@ def test_flat_index_n_padding_roundtrip_and_inertness():
     assert idx1.n == idx8.n == 7
     assert idx1.n_padded == 7 and idx8.n_padded == 8
     assert [s.offset for s in idx1.leaves] == [s.offset for s in idx8.leaves]
-    assert idx8.row_of.shape == (8,) and idx8.g_base.shape == (8,)
+    assert idx8.row_of.shape == (8,)
     buf = flat.flatten(idx8, tree)
     assert buf.shape == (8,)
     np.testing.assert_array_equal(np.asarray(buf)[7:], 0.0)
@@ -309,7 +309,7 @@ def test_run_rounds_zero_rounds_is_a_noop():
 def test_run_fl_zero_rounds_returns_empty_history():
     from repro.launch.train import run_fl
     hist = run_fl("smollm-135m", rounds=0, n_clients=4, local_steps=1,
-                  batch=2, seq_len=8, quiet=True)
+                  batch=2, seq_len=8, reduced=True, quiet=True)
     assert hist["round"] == [] and hist["final_acc"] is None
     assert hist["final_local_acc"] is None
 
