@@ -513,33 +513,37 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
                  else (lambda a: csh.constrain_cohort(a, mesh)))
 
     dens_fn = jax.vmap(functools.partial(_density_and_fraction, cfg, index))
-    if two_d:
-        # build each device's (m/D, N/n_model) density slice SHARD-LOCALLY:
-        # left to propagation, GSPMD reshards the per-leaf concatenate onto
-        # the model axis with a zero-pad + row-width all-reduce — exactly
-        # the model-replicated (m/D, N) transient this path retires
-        from jax.sharding import PartitionSpec as P
+    with jax.named_scope("fedfa.density"):
+        if two_d:
+            # build each device's (m/D, N/n_model) density slice
+            # SHARD-LOCALLY: left to propagation, GSPMD reshards the
+            # per-leaf concatenate onto the model axis with a zero-pad +
+            # row-width all-reduce — exactly the model-replicated (m/D, N)
+            # transient this path retires
+            from jax.sharding import PartitionSpec as P
 
-        def _dens_local(mk):
-            d, f = dens_fn(mk)
-            cols = index.n_padded // ms
-            k = jax.lax.axis_index(csh.MODEL_AXIS)
-            return jax.lax.dynamic_slice_in_dim(d, k * cols, cols, axis=1), f
+            def _dens_local(mk):
+                d, f = dens_fn(mk)
+                cols = index.n_padded // ms
+                k = jax.lax.axis_index(csh.MODEL_AXIS)
+                return (jax.lax.dynamic_slice_in_dim(d, k * cols, cols,
+                                                     axis=1), f)
 
-        dens, fracs = jax.shard_map(
-            _dens_local, mesh=mesh,
-            in_specs=(jax.tree.map(lambda _: P(csh.DATA_AXIS), masks),),
-            out_specs=(P(csh.DATA_AXIS, csh.MODEL_AXIS),
-                       P(csh.DATA_AXIS, None)),
-            check_vma=False)(masks)
-    else:
-        dens, fracs = dens_fn(masks)
-        dens = constrain(dens)
-    x_g = x
-    if graft and not pregrafted:
-        x_g = jax.vmap(functools.partial(_graft_flat, index))(
-            csh.constrain_cohort(x, mesh), gmaps)
-    x_g = constrain(x_g)
+            dens, fracs = jax.shard_map(
+                _dens_local, mesh=mesh,
+                in_specs=(jax.tree.map(lambda _: P(csh.DATA_AXIS), masks),),
+                out_specs=(P(csh.DATA_AXIS, csh.MODEL_AXIS),
+                           P(csh.DATA_AXIS, None)),
+                check_vma=False)(masks)
+        else:
+            dens, fracs = dens_fn(masks)
+            dens = constrain(dens)
+    with jax.named_scope("fedfa.graft"):
+        x_g = x
+        if graft and not pregrafted:
+            x_g = jax.vmap(functools.partial(_graft_flat, index))(
+                csh.constrain_cohort(x, mesh), gmaps)
+        x_g = constrain(x_g)
 
     if graft:
         dwrow = None   # grafting weights every depth slot equally (1.0)
@@ -551,17 +555,20 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
 
     alpha = None
     if scale:
-        # quantized rows arrive density-masked, so the mask multiply (an
-        # f32 (m, N) transient) only exists on the f32 path
-        xm = x_g if scales is not None else x_g * dens
-        norms = _cohort_norms(index, xm, fracs, trim, use_kernel, interpret,
-                              mesh, scales=scales)                  # (m, S)
-        # cross-client mean weighted by row validity: pad rows (n_data = 0)
-        # must not shift α; with every row valid this is exactly the mean
-        valid = (n_data > 0).astype(jnp.float32)                    # (m,)
-        mean_norms = jnp.sum(valid[:, None] * norms, axis=0, keepdims=True) \
-            / jnp.maximum(jnp.sum(valid), 1.0)
-        alpha = mean_norms / jnp.maximum(norms, eps)
+        with jax.named_scope("fedfa.quantile"):
+            # quantized rows arrive density-masked, so the mask multiply
+            # (an f32 (m, N) transient) only exists on the f32 path
+            xm = x_g if scales is not None else x_g * dens
+            norms = _cohort_norms(index, xm, fracs, trim, use_kernel,
+                                  interpret, mesh, scales=scales)   # (m, S)
+            # cross-client mean weighted by row validity: pad rows
+            # (n_data = 0) must not shift α; with every row valid this is
+            # exactly the mean
+            valid = (n_data > 0).astype(jnp.float32)                # (m,)
+            mean_norms = jnp.sum(valid[:, None] * norms, axis=0,
+                                 keepdims=True) \
+                / jnp.maximum(jnp.sum(valid), 1.0)
+            alpha = mean_norms / jnp.maximum(norms, eps)
 
     gather = functools.partial(_expand_segments, index)          # (m, N)
     if alpha is None:
@@ -569,29 +576,34 @@ def aggregate_buffers(index: FlatIndex, g_flat: jax.Array, x: jax.Array,
     else:
         warow = alpha if dwrow is None else dwrow * alpha
     ones_n = jnp.ones((index.n_padded,), jnp.float32)
-    if scales is not None:
-        # fused dequantize-accumulate: scale·gate·α collapse into one
-        # (m, S) weight table gathered per column INSIDE the kernel — the
-        # quantized rows are read exactly once, with no (m, N) f32 product
-        seg_id, _, _ = _segment_maps(index)
-        coeff = scales if warow is None else warow * scales
-        Mp = agg_ops.accumulate_quant(
-            x_g, n_data, coeff, jnp.asarray(seg_id), ones_n,
-            use_kernel=use_kernel, interpret=interpret, mesh=mesh,
-            cohort_2d=two_d)
-    else:
-        contrib = constrain(
-            x_g * dens if warow is None else x_g * dens * gather(warow))
-        Mp = agg_ops.accumulate(contrib, n_data, ones_n,
+    with jax.named_scope("fedfa.accumulate"):
+        if scales is not None:
+            # fused dequantize-accumulate: scale·gate·α collapse into one
+            # (m, S) weight table gathered per column INSIDE the kernel —
+            # the quantized rows are read exactly once, with no (m, N) f32
+            # product
+            seg_id, _, _ = _segment_maps(index)
+            coeff = scales if warow is None else warow * scales
+            Mp = agg_ops.accumulate_quant(
+                x_g, n_data, coeff, jnp.asarray(seg_id), ones_n,
+                use_kernel=use_kernel, interpret=interpret, mesh=mesh,
+                cohort_2d=two_d)
+        else:
+            contrib = constrain(
+                x_g * dens if warow is None else x_g * dens * gather(warow))
+            Mp = agg_ops.accumulate(contrib, n_data, ones_n,
+                                    use_kernel=use_kernel,
+                                    interpret=interpret, mesh=mesh,
+                                    cohort_2d=two_d)
+        counts = constrain(
+            dens if dwrow is None else dens * gather(dwrow))
+        Gm = agg_ops.accumulate(counts, n_data, ones_n,
                                 use_kernel=use_kernel, interpret=interpret,
                                 mesh=mesh, cohort_2d=two_d)
-    counts = constrain(
-        dens if dwrow is None else dens * gather(dwrow))
-    Gm = agg_ops.accumulate(counts, n_data, ones_n, use_kernel=use_kernel,
-                            interpret=interpret, mesh=mesh, cohort_2d=two_d)
 
-    upd = Mp / jnp.maximum(Gm, eps)
-    return jnp.where(Gm > 0, upd, g_flat)  # γ = 0 keeps the global value
+    with jax.named_scope("fedfa.merge"):
+        upd = Mp / jnp.maximum(Gm, eps)
+        return jnp.where(Gm > 0, upd, g_flat)  # γ = 0 keeps the global
 
 
 def aggregate_flat(global_params: Params, stacked_params: Params,

@@ -74,7 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core import flat
+from repro.core import flat, obs
 from repro.core.fedfa import STRATEGIES
 from repro.core.server import (ClientSpec, FLConfig, cohort_update,
                                default_class_masks, stack_runtimes)
@@ -265,17 +265,22 @@ def make_flat_round(cfg: ArchConfig, fl: FLConfig, index: flat.FlatIndex,
 
         def _round_q(g_buf, c_buf, s_buf, e_buf, es_buf, masks, gates,
                      gmaps, nd, cms, mal, batches, keys):
-            g = flat.unflatten(index, g_buf)
+            obs.count("round_traces")
+            with jax.named_scope("fedfa.unflatten"):
+                g = flat.unflatten(index, g_buf)
             updated, losses = cohort_update(
                 g, cfg, fl, masks, gates, batches, cms, mal, keys,
                 any_malicious=any_malicious)
-            x = cohort_sh.constrain_cohort(
-                flat.flatten_stacked(index, updated), mesh)         # (m, N)
-            if do_graft:
+            with jax.named_scope("fedfa.flatten"):
                 x = cohort_sh.constrain_cohort(
-                    jax.vmap(functools.partial(flat._graft_flat, index))(
-                        x, gmaps), mesh)
-            dens, _ = dens_fn(masks)
+                    flat.flatten_stacked(index, updated), mesh)     # (m, N)
+            if do_graft:
+                with jax.named_scope("fedfa.graft"):
+                    x = cohort_sh.constrain_cohort(
+                        jax.vmap(functools.partial(flat._graft_flat, index))(
+                            x, gmaps), mesh)
+            with jax.named_scope("fedfa.density"):
+                dens, _ = dens_fn(masks)
             # server-side error feedback: the residual of the PREVIOUS
             # quantized admission of this dispatch slot re-enters before
             # quantizing, so compression noise averages out across rounds
@@ -284,11 +289,12 @@ def make_flat_round(cfg: ArchConfig, fl: FLConfig, index: flat.FlatIndex,
             # width, and residual components outside its mask must not
             # leak values into coordinates whose density (and hence γ
             # weight) is 0 — the stored rows stay in the client subspace
-            y = (x + flat.dequantize_cohort(index, e_buf, es_buf)) \
-                * cohort_sh.constrain_cohort(dens, mesh)
-            x_q, scales = flat.quantize_cohort(index, y, fl.update_dtype)
-            e = y - flat.dequantize_cohort(index, x_q, scales)
-            e_q, e_s = flat.quantize_cohort(index, e, fl.update_dtype)
+            with jax.named_scope("fedfa.quantize"):
+                y = (x + flat.dequantize_cohort(index, e_buf, es_buf)) \
+                    * cohort_sh.constrain_cohort(dens, mesh)
+                x_q, scales = flat.quantize_cohort(index, y, fl.update_dtype)
+                e = y - flat.dequantize_cohort(index, x_q, scales)
+                e_q, e_s = flat.quantize_cohort(index, e, fl.update_dtype)
             g_new = flat.aggregate_buffers(
                 index, g_buf, cohort_sh.constrain_cohort_buffer(x_q, mesh),
                 cfg, masks, gates, gmaps, nd, trim=fl.trim, scales=scales,
@@ -313,7 +319,9 @@ def make_flat_round(cfg: ArchConfig, fl: FLConfig, index: flat.FlatIndex,
 
     def _round(g_buf, c_buf, masks, gates, gmaps, nd, cms, mal, batches,
                keys):
-        g = flat.unflatten(index, g_buf)           # leaf dtypes, inside trace
+        obs.count("round_traces")
+        with jax.named_scope("fedfa.unflatten"):
+            g = flat.unflatten(index, g_buf)       # leaf dtypes, inside trace
         updated, losses = cohort_update(
             g, cfg, fl, masks, gates, batches, cms, mal, keys,
             any_malicious=any_malicious)
@@ -322,8 +330,9 @@ def make_flat_round(cfg: ArchConfig, fl: FLConfig, index: flat.FlatIndex,
         # reductions split N immediately after, and the RETURNED cohort
         # buffer is sliced down to the resident 2-D P("data", "model")
         # layout for free
-        x = cohort_sh.constrain_cohort(
-            flat.flatten_stacked(index, updated), mesh)             # (m, N)
+        with jax.named_scope("fedfa.flatten"):
+            x = cohort_sh.constrain_cohort(
+                flat.flatten_stacked(index, updated), mesh)         # (m, N)
         g_new = flat.aggregate_buffers(
             index, g_buf, x, cfg, masks, gates, gmaps, nd, trim=fl.trim,
             use_kernel=fl.use_kernel, interpret=fl.interpret, mesh=mesh, **kw)
@@ -383,45 +392,47 @@ def flat_round(g_buf: jax.Array, c_buf, cfg: ArchConfig,
     host-side with inert rows (``sharding.cohort.pad_cohort``), so the
     returned cohort buffer has the padded row count.
     """
-    masks, gates, gmaps, nd, cms, mal = runtimes
-    m = int(nd.shape[0])
-    m_real = None
-    pad = cohort_sh.pad_rows(m, mesh)
-    if pad:
-        (masks, gates, gmaps, nd, cms, mal), batches = cohort_sh.pad_cohort(
-            runtimes, batches, pad)
-        m_real, m = m, m + pad
-    qmode = fl.update_dtype != "f32"
-    if qmode:
-        if not _quant_state_ok(c_buf, m, flat.update_dtype_of(
-                fl.update_dtype)):
-            c_buf = fresh_quant_state(index, m, fl.update_dtype)
-    elif c_buf is None or isinstance(c_buf, tuple) \
-            or c_buf.is_deleted() or c_buf.shape[0] != m:
-        # born in its resident layout: the next round's donated buffer
-        # then matches this one's, and the program is not traced again
-        c_buf = jnp.zeros((m, index.n_padded), jnp.float32, device=None
-                          if mesh is None
-                          else cohort_sh.cohort_buffer_sharding(mesh))
-    cms_in = default_class_masks(cms, cfg, fl, m)
-    # split per-client keys HOST-side (see make_flat_round), for the REAL
-    # rows only: padded cohorts must hand row i the same key the unpadded
-    # cohort would (the malicious label-shuffle consumes it), so pad rows
-    # reuse key 0
-    keys = jax.random.split(key, m if m_real is None else m_real)
-    if m_real is not None and m > m_real:
-        keys = jnp.concatenate(
-            [keys, jnp.broadcast_to(keys[:1],
-                                    (m - m_real,) + keys.shape[1:])])
-    fn = make_flat_round(cfg, fl, index, any_malicious=any_malicious,
-                         mesh=mesh, m_real=m_real)
-    if qmode:
-        g_buf, x_q, scales, e_q, e_s, loss = fn(
-            g_buf, *c_buf, masks, gates, gmaps, nd, cms_in, mal, batches,
-            keys)
-        return g_buf, (x_q, scales, e_q, e_s), loss
-    return fn(g_buf, c_buf, masks, gates, gmaps, nd, cms_in, mal, batches,
-              keys)
+    with obs.span("fedfa.prepare"):
+        masks, gates, gmaps, nd, cms, mal = runtimes
+        m = int(nd.shape[0])
+        m_real = None
+        pad = cohort_sh.pad_rows(m, mesh)
+        if pad:
+            (masks, gates, gmaps, nd, cms, mal), batches = \
+                cohort_sh.pad_cohort(runtimes, batches, pad)
+            m_real, m = m, m + pad
+        qmode = fl.update_dtype != "f32"
+        if qmode:
+            if not _quant_state_ok(c_buf, m, flat.update_dtype_of(
+                    fl.update_dtype)):
+                c_buf = fresh_quant_state(index, m, fl.update_dtype)
+        elif c_buf is None or isinstance(c_buf, tuple) \
+                or c_buf.is_deleted() or c_buf.shape[0] != m:
+            # born in its resident layout: the next round's donated buffer
+            # then matches this one's, and the program is not traced again
+            c_buf = jnp.zeros((m, index.n_padded), jnp.float32, device=None
+                              if mesh is None
+                              else cohort_sh.cohort_buffer_sharding(mesh))
+        cms_in = default_class_masks(cms, cfg, fl, m)
+        # split per-client keys HOST-side (see make_flat_round), for the
+        # REAL rows only: padded cohorts must hand row i the same key the
+        # unpadded cohort would (the malicious label-shuffle consumes it),
+        # so pad rows reuse key 0
+        keys = jax.random.split(key, m if m_real is None else m_real)
+        if m_real is not None and m > m_real:
+            keys = jnp.concatenate(
+                [keys, jnp.broadcast_to(keys[:1],
+                                        (m - m_real,) + keys.shape[1:])])
+        fn = make_flat_round(cfg, fl, index, any_malicious=any_malicious,
+                             mesh=mesh, m_real=m_real)
+    with obs.span("fedfa.program"):
+        if qmode:
+            g_buf, x_q, scales, e_q, e_s, loss = fn(
+                g_buf, *c_buf, masks, gates, gmaps, nd, cms_in, mal,
+                batches, keys)
+            return g_buf, (x_q, scales, e_q, e_s), loss
+        return fn(g_buf, c_buf, masks, gates, gmaps, nd, cms_in, mal,
+                  batches, keys)
 
 
 class ResidentDriver:
@@ -448,23 +459,25 @@ class ResidentDriver:
     def round(self, g_buf: jax.Array, specs: Sequence[ClientSpec], batches,
               key) -> Tuple[jax.Array, jax.Array]:
         """Run one round on the resident buffer: (g_buf', mean loss)."""
-        runtimes = stack_runtimes(self.cfg, specs)
-        m = len(specs)
-        m_rows = m + cohort_sh.pad_rows(m, self.mesh)
-        pool_key = (m_rows, self.fl.update_dtype)
-        g_buf, c_buf, loss = flat_round(
-            g_buf, self._cbufs.get(pool_key), self.cfg, self.fl, self.index,
-            runtimes, batches, key, mesh=self.mesh,
-            any_malicious=any(s.malicious for s in specs))
-        self._cbufs[pool_key] = c_buf
-        # evict entries whose buffer was donated elsewhere (e.g. handed to
-        # the async engine) — a deleted jax.Array is dead weight that would
-        # otherwise stay referenced forever
-        dead = lambda v: (any(b.is_deleted() for b in v)
-                          if isinstance(v, tuple) else v.is_deleted())
-        for k in [k for k, v in self._cbufs.items() if dead(v)]:
-            del self._cbufs[k]
-        return g_buf, loss
+        with obs.span("fedfa.round"):
+            with obs.span("fedfa.runtimes"):
+                runtimes = stack_runtimes(self.cfg, specs)
+            m = len(specs)
+            m_rows = m + cohort_sh.pad_rows(m, self.mesh)
+            pool_key = (m_rows, self.fl.update_dtype)
+            g_buf, c_buf, loss = flat_round(
+                g_buf, self._cbufs.get(pool_key), self.cfg, self.fl,
+                self.index, runtimes, batches, key, mesh=self.mesh,
+                any_malicious=any(s.malicious for s in specs))
+            self._cbufs[pool_key] = c_buf
+            # evict entries whose buffer was donated elsewhere (e.g. handed
+            # to the async engine) — a deleted jax.Array is dead weight that
+            # would otherwise stay referenced forever
+            dead = lambda v: (any(b.is_deleted() for b in v)
+                              if isinstance(v, tuple) else v.is_deleted())
+            for k in [k for k, v in self._cbufs.items() if dead(v)]:
+                del self._cbufs[k]
+            return g_buf, loss
 
 
 def run_rounds(global_params: Params, cfg: ArchConfig, fl: FLConfig,

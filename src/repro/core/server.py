@@ -158,11 +158,13 @@ def cohort_update(global_params: Params, cfg: ArchConfig, fl: FLConfig,
             out = honest
         return out, jnp.mean(losses)
 
-    if cms is None:
-        return jax.vmap(
-            lambda mk, gt, b, fl_, k: train_one(mk, gt, b, None, fl_, k)
-        )(masks, gates, client_batches, mal, keys)
-    return jax.vmap(train_one)(masks, gates, client_batches, cms, mal, keys)
+    with jax.named_scope("fedfa.train"):
+        if cms is None:
+            return jax.vmap(
+                lambda mk, gt, b, fl_, k: train_one(mk, gt, b, None, fl_, k)
+            )(masks, gates, client_batches, mal, keys)
+        return jax.vmap(train_one)(masks, gates, client_batches, cms, mal,
+                                   keys)
 
 
 def fl_round(global_params: Params, cfg: ArchConfig, fl: FLConfig,

@@ -8,7 +8,7 @@ import pytest
 from conftest import assert_tree_allclose as _assert_tree_allclose
 from conftest import fl_round_fixture, make_cohort
 
-from repro.core import flat
+from repro.core import flat, obs
 from repro.core import round as round_mod
 from repro.core.server import FLConfig, fl_round, fl_round_flat, \
     stack_runtimes
@@ -72,28 +72,39 @@ def test_round_donates_both_buffers(cohort):
 
 def test_round_compiles_once_per_cohort_shape(cohort):
     """Same cohort shape -> one executable; make_flat_round returns the
-    cached program and jit adds exactly one cache entry."""
+    cached program and jit adds exactly one cache entry.  The program's
+    own ``round_traces`` counter says the same without jax internals: one
+    more trace for a new cohort shape, none for a repeat round."""
     specs, data_fn = cohort
     fl = _fl("fedfa")
     index = flat.get_index(PARAMS)
     fn = round_mod.make_flat_round(CFG, fl, index, any_malicious=False)
     assert round_mod.make_flat_round(CFG, fl, index, any_malicious=False) is fn
-    if not hasattr(fn, "_cache_size"):    # private jax API; skip, don't break
-        pytest.skip("jitted-fn _cache_size unavailable in this jax")
+    # private jax API: checked where this jax has it
+    cache_size = getattr(fn, "_cache_size", None)
+    traces = lambda: obs.counts().get("round_traces", 0)
 
     driver = round_mod.ResidentDriver(CFG, fl, index)
     g_buf = flat.flatten(index, PARAMS)
-    for r in range(3):
+    g_buf, _ = driver.round(g_buf, specs, data_fn(0)[1], KEY)
+    before = traces()
+    assert before >= 1
+    for r in range(1, 3):
         g_buf, _ = driver.round(g_buf, specs, data_fn(r)[1],
                                 jax.random.fold_in(KEY, r))
-    assert fn._cache_size() == 1          # 3 rounds, same shape: 1 executable
+    assert traces() == before             # repeat rounds trace nothing
+    if cache_size:
+        assert cache_size() == 1          # 3 rounds, same shape: 1 executable
 
     # a different cohort shape compiles exactly one more program
     _, b0 = data_fn(0)
-    g_buf, _ = driver.round(g_buf, specs[:2],
-                            {k: v[:2] for k, v in b0.items()},
-                            jax.random.fold_in(KEY, 99))
-    assert fn._cache_size() == 2
+    b2 = {k: v[:2] for k, v in b0.items()}
+    g_buf, _ = driver.round(g_buf, specs[:2], b2, jax.random.fold_in(KEY, 99))
+    assert traces() == before + 1
+    g_buf, _ = driver.round(g_buf, specs[:2], b2, jax.random.fold_in(KEY, 98))
+    assert traces() == before + 1
+    if cache_size:
+        assert cache_size() == 2
 
 
 def test_fl_round_flat_matches_fl_round(cohort):
