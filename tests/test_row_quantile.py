@@ -280,3 +280,198 @@ def test_dispatch_long_rows_take_multilevel_not_oracle():
     t2, ss2 = ml.row_trimmed_stats_multilevel(rows, q, interpret=True)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
     np.testing.assert_array_equal(np.asarray(ss1), np.asarray(ss2))
+
+
+# ---------------------------------------------------------------------------
+# nibble levels: exact prefix compare, parted rank paths, Σx² over 8 levels
+# ---------------------------------------------------------------------------
+
+def _nibble_stats(path, segs, q):
+    """(t, ss), both (m, S), over ``segs`` (a list of S (m, len) f32
+    arrays) on the row path (S = 1) or through ``segmented_trimmed_stats``
+    with the segments laid side by side and the slice padded to the tile
+    with inert columns."""
+    q = jnp.asarray(q, jnp.float32)
+    if path == "row":
+        assert len(segs) == 1
+        t, ss = ml.row_trimmed_stats_multilevel(jnp.asarray(segs[0]),
+                                                q[:, 0], interpret=True)
+        return np.asarray(t)[:, None], np.asarray(ss)[:, None]
+    lens = [s.shape[1] for s in segs]
+    pad = (-sum(lens)) % ml.TILE
+    x = np.concatenate(list(segs) + [np.zeros((q.shape[0], pad),
+                                              np.float32)], axis=1)
+    seg_id = np.concatenate([np.full(n, s, np.int32)
+                             for s, n in enumerate(lens)]
+                            + [np.full(pad, -1, np.int32)])
+    t, ss = ml.segmented_trimmed_stats(
+        jnp.asarray(x), jnp.asarray(seg_id), jnp.asarray(lens, jnp.int32), q,
+        interpret=True)
+    return np.asarray(t), np.asarray(ss)
+
+
+def _check_nibble(path, segs, q, rtol_ss):
+    """Each (row, segment) against jnp.quantile on its magnitudes: integral
+    ranks bit-equal, every threshold within 1 ulp and bracketed by the
+    'lower'/'higher' order statistics, and the trimmed Σx² against the
+    sort-based sum of the squared magnitudes up to the threshold."""
+    t, ss = _nibble_stats(path, segs, q)
+    q = np.asarray(q, np.float32)
+    for s, seg in enumerate(segs):
+        a = np.abs(seg).astype(np.float32)
+        qs = jnp.asarray(q[:, s])
+        ref = np.asarray(jax.vmap(jnp.quantile)(jnp.asarray(a), qs))
+        lo = np.asarray(jax.vmap(lambda r, qq: jnp.quantile(
+            r, qq, method="lower"))(jnp.asarray(a), qs))
+        hi = np.asarray(jax.vmap(lambda r, qq: jnp.quantile(
+            r, qq, method="higher"))(jnp.asarray(a), qs))
+        p = q[:, s] * np.float32(a.shape[1] - 1)
+        exact = p == np.floor(p)
+        np.testing.assert_array_equal(t[exact, s], ref[exact])
+        assert (_ulp_dist(t[:, s], ref) <= 1).all(), (t[:, s], ref)
+        assert (t[:, s] >= lo).all() and (t[:, s] <= hi).all()
+        srt = np.sort(a, axis=1).astype(np.float64)
+        for c in range(a.shape[0]):
+            k = np.searchsorted(srt[c], t[c, s], side="right")
+            np.testing.assert_allclose(ss[c, s], np.sum(srt[c, :k] ** 2),
+                                       rtol=rtol_ss, atol=0)
+    return t, ss
+
+
+def _signed(a, rng):
+    return np.where(rng.random(a.shape) < 0.5, -a, a).astype(np.float32)
+
+
+def _last_nibble_seg(m, n, prefix_value, seed):
+    """(m, n) rows whose magnitudes share every bit but the last nibble of
+    ``prefix_value``, with a fifth of them zero (masked width): every order
+    statistic past the zeros resolves only at the last level, where the
+    prefix ``bits >> 4`` lies past 2^24."""
+    rng = np.random.default_rng(seed)
+    base = int(np.float32(prefix_value).view(np.int32)) & ~0xF
+    assert base >> 4 >= 1 << 24
+    bits = (base + rng.integers(0, 16, size=(m, n))).astype(np.int32)
+    a = bits.view(np.float32)
+    a = np.where(rng.random((m, n)) < 0.2, np.float32(0.0), a)
+    return _signed(a, rng)
+
+
+@pytest.mark.parametrize("path", ["row", "segmented"])
+def test_multilevel_last_nibble_prefix_exact(path):
+    """Order statistics that agree in all but their last nibble: the last
+    level's in-bracket test compares a prefix past 2^24, which an f32
+    gather would round onto a neighbour and so empty the bracket."""
+    q_row = [0.95, 0.5, 1.0]
+    if path == "row":
+        segs = [_last_nibble_seg(3, 2000, 3.14159, seed=0)]
+        q = np.asarray(q_row, np.float32)[:, None]
+    else:
+        segs = [_last_nibble_seg(3, n, v, seed=s) for s, (n, v) in
+                enumerate([(500, 3.14159), (260, 1234.567), (264, 0.0271)])]
+        q = np.asarray([q_row, [0.97, 1.0, 0.6], [0.51, 0.9737, 0.95]],
+                       np.float32)
+    t, _ = _check_nibble(path, segs, q, rtol_ss=1e-6)
+    # the thresholds really are last-nibble neighbours of the prefix
+    for s, seg in enumerate(segs):
+        top = np.abs(seg).view(np.int32).max(axis=1) >> 4
+        assert ((t[:, s].view(np.int32) >> 4) == top).all()
+
+
+def _parted_seg(m, n, seed):
+    """(m, n) rows with half their magnitudes in [1, 2) and half in [2, 4):
+    the top nibble of the bit pattern is 3 for the first half and 4 for the
+    second, and the level that splits them is the first."""
+    rng = np.random.default_rng(seed)
+    k = n // 2
+    a = np.concatenate([rng.uniform(1.0, 2.0, (m, k)),
+                        rng.uniform(2.0, 4.0, (m, n - k))], axis=1)
+    a = np.take_along_axis(a, rng.permuted(
+        np.tile(np.arange(n), (m, 1)), axis=1), axis=1)
+    return _signed(a.astype(np.float32), rng), k
+
+
+@pytest.mark.parametrize("path", ["row", "segmented"])
+def test_multilevel_rank_paths_part_at_first_level(path):
+    """The floor and ceil ranks fall in different bins of the first level
+    (top nibbles 3 and 4), so the two rank paths refine different brackets
+    from the start and the threshold interpolates across them."""
+    lens = [1000] if path == "row" else [700, 324]
+    segs, qs = [], []
+    for s, n in enumerate(lens):
+        seg, k = _parted_seg(2, n, seed=10 + s)
+        q = np.float32((k - 0.5) / (n - 1))
+        p = q * np.float32(n - 1)
+        assert np.floor(p) == k - 1 and p > k - 1    # floor in [1, 2), ceil in [2, 4)
+        segs.append(seg)
+        qs.append(q)
+    q = np.tile(np.asarray(qs, np.float32), (2, 1))
+    t, _ = _check_nibble(path, segs, q, rtol_ss=1e-6)
+    for s, seg in enumerate(segs):
+        srt = np.sort(np.abs(seg), axis=1)
+        k = lens[s] // 2
+        v0, v1 = srt[:, k - 1], srt[:, k]
+        assert ((v0.view(np.int32) >> 28) == 3).all()
+        assert ((v1.view(np.int32) >> 28) == 4).all()
+        assert ((t[:, s] > v0) & (t[:, s] < v1)).all()
+
+
+def _every_level_seg(m, n, seed):
+    """(m, n) rows built round a target bit pattern so that the bracket of
+    every one of the 8 levels holds mass strictly below the target's bin and
+    above it, plus ties of the target: each level's Σx² below-sum counts.
+    Returns the rows and the target's rank (its first tie)."""
+    rng = np.random.default_rng(seed)
+    target = int(np.float32(0.7071).view(np.int32))
+    out = np.empty((m, n), np.int32)
+    ranks = []
+    for c in range(m):
+        vals = []
+        for j in range(8):
+            sh = 28 - 4 * j
+            nib = (target >> sh) & 0xF
+            keep = (target >> (sh + 4)) << (sh + 4) if sh + 4 < 32 else 0
+            low = (1 << sh) - 1
+            if nib > 0:      # same prefix, a smaller nibble: below at level j
+                b = rng.integers(0, nib, 6)
+                vals += [keep | (int(x) << sh) | int(rng.integers(0, low + 1))
+                         for x in b]
+            top = 5 if j == 0 else 16    # x² stays finite: below 2^33
+            if nib + 1 < top:  # same prefix, a larger nibble: above at level j
+                b = rng.integers(nib + 1, top, 6)
+                vals += [keep | (int(x) << sh) | int(rng.integers(0, low + 1))
+                         for x in b]
+        ties = [target] * 3
+        rest = n - len(vals) - len(ties)
+        wide = np.abs(rng.standard_normal(rest)).astype(np.float32)
+        row = np.concatenate([np.asarray(vals + ties, np.int32),
+                              wide.view(np.int32)])
+        row = row[(row >= 0) & (row < 0x7F800000)]   # finite magnitudes
+        assert row.size == n
+        out[c] = rng.permutation(row)
+        ranks.append(int(np.sum(row < target)))
+    return _signed(out.view(np.float32), rng), np.asarray(ranks)
+
+
+@pytest.mark.parametrize("path", ["row", "segmented"])
+def test_multilevel_trimmed_sum_over_all_levels(path):
+    """Σx² at the threshold against the sort-based trimmed sum, on rows
+    whose target order statistic has mass below its bin at each of the 8
+    levels: the strictly-below sums of levels 0..6 and the inclusive sum of
+    the last all enter, and their total matches to f32 rounding."""
+    lens = [3000] if path == "row" else [1500, 548]
+    segs, qs = [], []
+    for s, n in enumerate(lens):
+        seg, ranks = _every_level_seg(2, n, seed=20 + s)
+        segs.append(seg)
+        # a quantile level whose floor rank is the target's first tie
+        qs.append([np.float32(r) / np.float32(n - 1) + np.float32(1e-7)
+                   for r in ranks])
+    q = np.asarray(qs, np.float32).T
+    t, ss = _check_nibble(path, segs, q, rtol_ss=1e-6)
+    for s, seg in enumerate(segs):
+        target = np.float32(0.7071)
+        a = np.abs(seg)
+        assert (t[:, s] >= target).all()
+        # mass strictly below the target's bin at every level is included
+        below = np.where(a < target, a.astype(np.float64) ** 2, 0).sum(1)
+        assert (ss[:, s] > below).all()

@@ -73,6 +73,18 @@ def test_hist_call_compiles(one_chip, rows, cols, segs):
              ((rows, 2, segs), jnp.int32), ((), jnp.int32))
 
 
+def test_hist_call_int8_row_path_compiles(one_chip):
+    """int8 admission on the 1-D path: the rows stay int8 in HBM and each
+    row's (rows, 1) scale dequantizes them in the kernel."""
+    def level(x, seg, sc, hi, shift):
+        return multilevel._hist_call(x, seg, sc, hi, shift, interpret=False)
+
+    rows, cols = 96, D * D
+    _compile(level, one_chip, ((rows, cols), jnp.int8),
+             ((cols,), jnp.int32), ((rows, 1), jnp.float32),
+             ((rows, 2, 1), jnp.int32), ((), jnp.int32))
+
+
 @pytest.mark.parametrize("rows,length", [
     (LAYERS, D * D_KV),        # wk/wv of one client: 30 x 110,592
     (M * LAYERS, D),           # the per-layer norm weights of the cohort
