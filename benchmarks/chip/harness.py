@@ -27,14 +27,7 @@ if os.path.join(ROOT, "src") not in sys.path:
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 N_CHECKED = 3
 
-# configuration file key -> the program's ArchConfig field
-_ARCH_FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "d_head", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "tie_word_embeddings": "tie_embeddings",
-    "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-}
+# fedfa block key -> the program's ArchConfig field
 _FEDFA_FIELDS = {"n_sections": "n_sections", "momentum": "momentum",
                  "weight_decay": "weight_decay",
                  "local_optimizer": "optimizer"}
@@ -57,25 +50,19 @@ def enable_compile_cache() -> str:
 def program_config(cfg: dict):
     """The program's ArchConfig for a configuration file: the registered
     architecture, with the keys the file lists in ``reduced`` replaced;
-    every other size has to agree with the file."""
+    every other size has to agree with the file.  The architecture's keys
+    are the configuration's family's (``families/<family>.py``); the
+    ``fedfa`` block is every family's."""
     from repro.configs import get_arch
-    pcfg = get_arch(cfg["registry"])
-    want = {f: cfg[k] for k, f in _ARCH_FIELDS.items()}
-    want.update({f: cfg["fedfa"][k] for k, f in _FEDFA_FIELDS.items()})
-    red = set(cfg.get("reduced", []))
-    over = {f: cfg[k] for k, f in _ARCH_FIELDS.items() if k in red}
-    over.update({f: cfg["fedfa"][k] for k, f in _FEDFA_FIELDS.items()
-                 if "fedfa" in red})
-    if over:
-        pcfg = pcfg.replace(**over)
-    bad = {f: (getattr(pcfg, f), v) for f, v in want.items()
-           if getattr(pcfg, f) != v}
+    pcfg = spec.family_of(cfg).program_config(cfg, get_arch(cfg["registry"]))
+    fed = cfg["fedfa"]
+    if "fedfa" in cfg.get("reduced", []):
+        pcfg = pcfg.replace(**{f: fed[k] for k, f in _FEDFA_FIELDS.items()})
+    bad = {f: (getattr(pcfg, f), fed[k]) for k, f in _FEDFA_FIELDS.items()
+           if getattr(pcfg, f) != fed[k]}
     if bad:
         raise ValueError(f"the program's {cfg['registry']} differs from "
                          f"{cfg['name']}.json: {bad}")
-    if pcfg.layer_pattern != ("attn",) or pcfg.moe or pcfg.act != "silu" \
-            or pcfg.norm != "rmsnorm" or pcfg.logit_softcap is not None:
-        raise ValueError("the reference covers dense attention blocks only")
     return pcfg
 
 

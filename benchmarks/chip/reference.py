@@ -1,11 +1,13 @@
 """Plain reference of the timed FedFA round, written from the paper's
 Alg. 1-3 and the configuration alone; it imports nothing of the program.
 
-Each client trains its sub-model as a small dense model: the first
-``d_model`` hidden channels, the first heads and key/value heads, the
-first ``d_ff`` MLP channels (width class), and the first d_s layers of
-every depth section.  Local training is SGD with momentum and weight
-decay on the next-token loss.  The server then grafts (a missing layer
+Each client trains its sub-model: the prefix of every axis that its width
+class keeps (for the dense family the first ``d_model`` hidden channels,
+heads and key/value heads and ``d_ff`` MLP channels), and the first d_s
+layers of every depth section; the configuration's family
+(``families/<family>.py``) gives those prefixes and the sub-model's
+forward pass.  Local training is SGD with momentum and weight decay on
+the next-token loss.  The server then grafts (a missing layer
 takes the section's last trained layer), takes every client's trimmed
 norm per layer tensor (L2 norm of the entries whose magnitude is at or
 under the ``trim`` quantile of the client's active entries), scales each
@@ -13,9 +15,9 @@ client's tensor by alpha = mean norm / own norm, and merges
 M = sum_c n_c alpha_c W_c / sum_c n_c over the clients holding each entry;
 an entry no client holds keeps its value.
 
-Every matmul runs at HIGHEST precision in float32.  ``dtype=bfloat16``
-computes the whole reference in bfloat16 instead: the control that a
-correct check must refuse.
+Every matmul of a family's forward pass runs at HIGHEST precision in
+float32.  ``dtype=bfloat16`` computes the whole reference in bfloat16
+instead: the control that a correct check must refuse.
 """
 from __future__ import annotations
 
@@ -26,42 +28,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import spec
 import traffic as traffic_mod
 import weights as weights_mod
 import work
 
-HIGHEST = jax.lax.Precision.HIGHEST
 
-# leaf name suffix -> which active size each trailing axis keeps
-_BLOCKS = {
-    "embed": (None, "d"),
-    "final_norm/scale": ("d",),
-    "lm_head": ("d", None),
-    "attn/wq": ("d", "hq"),
-    "attn/wk": ("d", "hkv"),
-    "attn/wv": ("d", "hkv"),
-    "attn/wo": ("hq", "d"),
-    "ffn/w_gate": ("d", "f"),
-    "ffn/w_up": ("d", "f"),
-    "ffn/w_down": ("f", "d"),
-    "ln1/scale": ("d",),
-    "ln2/scale": ("d",),
-}
+def loss(p: Dict[str, jax.Array], tokens: jax.Array, cfg: dict,
+         sizes: Dict[str, Tuple[int, ...]]) -> jax.Array:
+    """Mean next-token cross entropy of the sub-model ``p`` (its tensors
+    by leaf name inside a block, ``sizes`` their active trailing sizes) on
+    ``tokens`` (B, S): the configuration's family's forward pass."""
+    return spec.family_of(cfg).loss(p, tokens, cfg, sizes)
 
 
-def _short(name: str) -> str:
-    return name.split("stages/0/0/")[-1]
-
-
-def block_sizes(cfg: dict, leaf: weights_mod.Leaf, width: float):
-    """Active size of each trailing axis of ``leaf`` for a width class."""
-    sz = work.width_sizes(cfg, width)
-    hd = cfg["head_dim"]
-    act = {"d": sz["d_model"], "f": sz["d_ff"], "hq": sz["n_heads"] * hd,
-           "hkv": sz["n_kv_heads"] * hd}
-    trail = leaf.shape[1:] if leaf.stacked else leaf.shape
-    return tuple(n if a is None else act[a]
-                 for n, a in zip(trail, _BLOCKS[_short(leaf.name)]))
+def sub_sizes(cfg: dict, width: float) -> Dict[str, Tuple[int, ...]]:
+    """Every leaf's active trailing sizes in a width class, by its name
+    inside a block."""
+    fam = spec.family_of(cfg)
+    return {l.short: fam.axis_sizes(cfg, l, width)
+            for l in weights_mod.layout(cfg)[0]}
 
 
 def active_layers(cfg: dict, depths: Sequence[int]) -> List[int]:
@@ -84,88 +70,22 @@ def graft_rows(cfg: dict, depths: Sequence[int]) -> np.ndarray:
     return np.asarray(out, np.int32)
 
 
-# ---------------------------------------------------------------------------
-# the sub-model: a dense decoder LM
-# ---------------------------------------------------------------------------
-
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=HIGHEST)
-
-
-def _rms(x, scale, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1 + scale)
-
-
-def _rope(x, theta):
-    """Rotary positions over the two halves of each head."""
-    S, hd = x.shape[-3], x.shape[-1]
-    half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs       # (S, h/2)
-    cos = jnp.cos(ang)[:, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def loss(p: Dict[str, jax.Array], tokens: jax.Array, cfg: dict,
-         hq: int, hkv: int) -> jax.Array:
-    """Mean next-token cross entropy of the sub-model ``p`` on
-    ``tokens`` (B, S); ``hq``/``hkv`` are its query and key/value head
-    counts."""
-    B, S = tokens.shape
-    hd = cfg["head_dim"]
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    V = cfg["vocab_size"]
-    dt = p["embed"].dtype
-    x = p["embed"][tokens]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    group = hq // hkv
-
-    def layer(x, w):
-        h = _rms(x, w["ln1/scale"], eps)
-        q = _rope(_mm(h, w["attn/wq"]).reshape(B, S, hq, hd), theta)
-        k = _rope(_mm(h, w["attn/wk"]).reshape(B, S, hkv, hd), theta)
-        v = _mm(h, w["attn/wv"]).reshape(B, S, hkv, hd)
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) \
-            * jnp.asarray(hd ** -0.5, dt)
-        s = jnp.where(causal, s, jnp.asarray(-1e30, dt))
-        a = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", a, v, precision=HIGHEST)
-        x = x + _mm(o.reshape(B, S, hq * hd), w["attn/wo"])
-        h = _rms(x, w["ln2/scale"], eps)
-        f = jax.nn.silu(_mm(h, w["ffn/w_gate"])) * _mm(h, w["ffn/w_up"])
-        return x + _mm(f, w["ffn/w_down"]), None
-
-    layers = {k: v for k, v in p.items() if "/" in k and k not in
-              ("final_norm/scale",)}
-    x, _ = jax.lax.scan(layer, x, layers)
-    x = _rms(x, p["final_norm/scale"], eps)
-    head = p["lm_head"][:, :V] if "lm_head" in p else p["embed"][:V].T
-    logits = _mm(x, head)[:, :-1]
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(lp, tokens[:, 1:, None], axis=-1)[..., 0]
-    return jnp.mean(nll)
-
-
 @functools.lru_cache(maxsize=None)
-def _trainer(cfg_key: str, hq: int, hkv: int, lr: float, mom: float,
-             wd: float, half_batch: bool):
-    """Jitted local training of a group of clients with one sub-model
-    shape: (shared start params, tokens (n, E, B, S)) -> (params (n, ...),
-    mean losses (n,))."""
+def _trainer(cfg_key: str, width: float, lr: float, mom: float, wd: float,
+             half_batch: bool):
+    """Jitted local training of a group of clients of one width class:
+    (shared start params, tokens (n, E, B, S)) -> (params (n, ...), mean
+    losses (n,))."""
     import json
     cfg = json.loads(cfg_key)
+    sizes = sub_sizes(cfg, width)
 
     def one(p0, toks):
         def step(carry, tok):
             p, m = carry
             if half_batch:
                 tok = tok[: tok.shape[0] // 2]
-            l, g = jax.value_and_grad(loss)(p, tok, cfg, hq, hkv)
+            l, g = jax.value_and_grad(loss)(p, tok, cfg, sizes)
             m = jax.tree.map(lambda m_, g_, p_: mom * m_ + (g_ + wd * p_),
                              m, g, p)
             p = jax.tree.map(lambda p_, m_: p_ - lr * m_, p, m)
@@ -184,16 +104,17 @@ def _extractor(cfg_key: str, width: float, depths: Tuple[int, ...], dtype):
     import json
     cfg = json.loads(cfg_key)
     leaves, _ = weights_mod.layout(cfg)
+    sizes = sub_sizes(cfg, width)
     rows = np.asarray(active_layers(cfg, depths))
 
     @jax.jit
     def extract(g):
         sub = {}
         for l in leaves:
-            b = tuple(slice(0, n) for n in block_sizes(cfg, l, width))
+            b = tuple(slice(0, n) for n in sizes[l.short])
             x = g[l.name]
             x = x[rows][(slice(None),) + b] if l.stacked else x[b]
-            sub[_short(l.name)] = x.astype(dtype)
+            sub[l.short] = x.astype(dtype)
         return sub
 
     return extract
@@ -253,18 +174,16 @@ def run_round(g: Dict[str, jax.Array], rnd: traffic_mod.Round, cfg: dict,
     # classes in one fixed order, so the merge compiles once for a cell
     # and not once for every order in which a round's clients arrive
     for (w, depths), idx in sorted(groups.items()):
-        sz = work.width_sizes(cfg, w)
         sub = _extractor(key, w, depths, dtype)(g)
         toks = jnp.asarray(rnd.tokens[np.asarray(idx)])
-        fn = _trainer(key, sz["n_heads"], sz["n_kv_heads"], float(lr), mom,
-                      wd, half_batch)
+        fn = _trainer(key, w, float(lr), mom, wd, half_batch)
         p, ls = fn(sub, toks)
         losses[idx] = np.asarray(ls, np.float64)
         nd = jnp.asarray([rnd.clients[i].n_data for i in idx], jnp.float32)
         trained.append((depths, p, nd))
     new = {}
     for l in leaves:
-        s = _short(l.name)
+        s = l.short
         grafts = tuple(jnp.asarray(graft_rows(cfg, d)) if l.stacked else None
                        for d, _, _ in trained)
         new[l.name] = _merge_leaf(tuple(p[s] for _, p, _ in trained), grafts,

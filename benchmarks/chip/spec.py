@@ -1,14 +1,18 @@
 """Finding a cell's files by name: ``BENCHMARK.json`` names each cell's
 configuration and traffic mix, and each lives in a file of its own
 (``configs/<config>.json``, ``traffic/<traffic>.json``); each per-layer
-metric is a reader ``metrics/<name>.py`` with a ``read(ctx)`` function.
-Adding a cell or a metric adds files and entries; nothing here changes.
+metric is a reader ``metrics/<name>.py`` with a ``read(ctx)`` function; a
+configuration's model family (its ``"family"`` key, ``"dense"`` where the
+file has none) is a module ``families/<family>.py``.  Adding a cell, a
+metric or a model family adds files and entries; nothing here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+from types import ModuleType
 from typing import Callable, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -60,11 +64,32 @@ def cell_metrics(bench: dict, cell: str, section: str) -> List[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def reader(name: str) -> Callable[[dict], Optional[float]]:
-    """``metrics/<name>.py``'s ``read``."""
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+@functools.lru_cache(maxsize=None)
+def _load(path: str, modname: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def _module(kind: str, name: str) -> ModuleType:
+    """``<kind>/<name>.py``, loaded once a process."""
+    return _load(os.path.join(HERE, kind, name + ".py"),
+                 f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"))
+
+
+def reader(name: str) -> Callable[[dict], Optional[float]]:
+    """``metrics/<name>.py``'s ``read``."""
+    return _module("metrics", name).read
+
+
+def family(name: str) -> ModuleType:
+    """``families/<name>.py``: a model family's ``program_config``,
+    ``shapes``, ``axis_sizes``, ``loss`` and ``train_flops``."""
+    return _module("families", name)
+
+
+def family_of(cfg: dict) -> ModuleType:
+    """The family a configuration names (``"dense"`` where it names
+    none)."""
+    return family(cfg.get("family", "dense"))

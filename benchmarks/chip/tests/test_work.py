@@ -53,7 +53,7 @@ def test_paper_tf_thin_client_step_flops():
                                        "n_kv_heads": 2, "d_ff": 576}),
 ])
 def test_width_sizes(name, w, want):
-    assert work.width_sizes(_cfg(name), w) == want
+    assert spec.family("dense").width_sizes(_cfg(name), w) == want
 
 
 def test_least_bytes_and_bound():

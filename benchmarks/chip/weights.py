@@ -2,10 +2,11 @@
 tree layout the program consumes, and the flat layout of that tree.
 
 The layout is the benchmark's own statement of the program's parameter
-tree (``{"embed", "final_norm", ["lm_head"], "stages": ((block,),)}``,
-each block's leaves stacked over the layers); ``harness`` checks it
-against the program's ``init_params`` shapes before a run, so a change of
-the program's layout stops the benchmark instead of misreading it.
+tree (global leaves beside ``"stages": ((block,),)``, each block's leaves
+stacked over the layers), as the configuration's family
+(``families/<family>.py``) gives it; ``harness`` checks it against the
+program's ``init_params`` shapes before a run, so a change of the
+program's layout stops the benchmark instead of misreading it.
 """
 from __future__ import annotations
 
@@ -16,28 +17,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import work
+import spec
+
+STACK = "stages/0/0/"
 
 
 def shapes(cfg: dict) -> dict:
-    """Parameter shapes, as the tree the program consumes."""
-    D, F, R = (cfg["hidden_size"], cfg["intermediate_size"],
-               cfg["num_hidden_layers"])
-    H, K, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["head_dim"])
-    Vp = work.padded_vocab(cfg)
-    block = {
-        "attn": {"wq": (R, D, H * hd), "wk": (R, D, K * hd),
-                 "wv": (R, D, K * hd), "wo": (R, H * hd, D)},
-        "ffn": {"w_gate": (R, D, F), "w_up": (R, D, F), "w_down": (R, F, D)},
-        "ln1": {"scale": (R, D)},
-        "ln2": {"scale": (R, D)},
-    }
-    tree = {"embed": (Vp, D), "final_norm": {"scale": (D,)},
-            "stages": ((block,),)}
-    if not cfg["tie_word_embeddings"]:
-        tree["lm_head"] = (D, Vp)
-    return tree
+    """Parameter shapes, as the tree the program consumes: the
+    configuration's family states it."""
+    return spec.family_of(cfg).shapes(cfg)
 
 
 def _is_shape(x) -> bool:
@@ -51,6 +39,11 @@ class Leaf:
     offset: int
     size: int
     stacked: bool            # leading axis = layers
+
+    @property
+    def short(self) -> str:
+        """The name inside a block ("attn/wq"), or a global leaf's."""
+        return self.name.split(STACK)[-1]
 
 
 def layout(cfg: dict) -> Tuple[List[Leaf], int]:

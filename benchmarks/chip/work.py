@@ -1,38 +1,14 @@
 """Work counts the per-layer metrics divide by: the FLOPs a client's
-sub-model needs for local training, and the least bytes the trimmed-norm
-quantile and the (M', gamma) accumulate must move.
-
-The FLOP count is a copy of the dense-attention part of the program's
-analytic model (``launch/costs.macs_per_client``): matmul-level
-accounting of the sub-model at the client's width class and section
-depths, forward + backward = 3x forward, causal attention at half the
-square.  Masked padding and recomputation are not counted: the padded
-dense program does more, and that is what ``mfu`` is meant to show.
+sub-model needs for local training (counted by the configuration's family,
+``families/<family>.py``), the FedFA depth sections, and the least bytes
+the trimmed-norm quantile and the (M', gamma) accumulate must move.
 """
 from __future__ import annotations
 
 import math
 from typing import Sequence
 
-
-def width_sizes(cfg: dict, w: float) -> dict:
-    """Contiguous-prefix active sizes of width class ``w`` (HeteroFL-style
-    structured pruning, as FedFA's width flexibility defines it)."""
-    if not 0.0 < w <= 1.0:
-        raise ValueError(f"width class must be in (0, 1], got {w!r}")
-    H, K = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    kv = max(1, int(round(w * K)))
-    return {
-        "d_model": D if w >= 1.0 else max(16, int(w * D) // 8 * 8),
-        "n_heads": kv * (H // K),
-        "n_kv_heads": kv,
-        "d_ff": F if w >= 1.0 else max(8, int(w * F) // 8 * 8),
-    }
-
-
-def padded_vocab(cfg: dict) -> int:
-    return (cfg["vocab_size"] + 127) // 128 * 128
+import spec
 
 
 def section_bounds(cfg: dict):
@@ -58,18 +34,9 @@ def section_depths(cfg: dict, depth_frac: float):
 def train_flops(cfg: dict, width: float, depths: Sequence[int], batch: int,
                 seq: int) -> float:
     """FLOPs of one local step (forward + backward) of one client's
-    sub-model on a (batch, seq) batch: 2 x the MACs of
-    ``costs.macs_per_client``."""
-    sz = width_sizes(cfg, width)
-    D, hd = sz["d_model"], cfg["head_dim"]
-    H, K, F = sz["n_heads"], sz["n_kv_heads"], sz["d_ff"]
-    B, S = float(batch), float(seq)
-    proj = 2 * B * S * D * (H + 2 * K) * hd + 2 * B * S * H * hd * D
-    attn = 2 * 2 * B * S * (S / 2) * H * hd
-    ffn = 2 * 3 * B * S * D * F
-    layers = sum(depths)
-    fwd = layers * (proj + attn + ffn) + 2 * B * S * D * padded_vocab(cfg)
-    return 3.0 * fwd
+    sub-model on a (batch, seq) batch, as the configuration's family
+    counts them."""
+    return spec.family_of(cfg).train_flops(cfg, width, depths, batch, seq)
 
 
 def quantile_least_bytes(m: int, n: int) -> float:
